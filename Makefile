@@ -124,7 +124,7 @@ wire-smoke:
 # (docs/multichip.md): device-count curve + small campaign, bytes
 # asserted identical across mesh sizes
 multichip-smoke:
-	$(PY) scripts/multichip_campaign.py --smoke
+	JAX_PLATFORMS=cpu $(PY) scripts/multichip_campaign.py --smoke
 
 # the persistent streaming sweep service (docs/streaming.md): stream ==
 # chunked bytes, refill-schedule invariance, v9 interrupt/resume,

@@ -10,14 +10,44 @@ Grew out of scripts/sweep_million.py's one-script hack; now a first-class
 metric shared by the explore demo, the campaign bench leg, and the
 spec-as-data tests (tests/test_fault_params.py), so "compiles in the
 timed region" is reported the same way everywhere.
+
+``use_compile_cache()`` turns on JAX's persistent compile cache for an
+entry point (chip_smoke.py, bench.py, the scripts that compile device
+programs); importing the library never does.
 """
 
 from __future__ import annotations
 
 import logging
+import os
 from contextlib import contextmanager
+from typing import Optional
 
 import jax
+
+# a fixed path, so a later process on the same checkout finds what an
+# earlier one compiled (a per-run directory would never hit)
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
+def use_compile_cache() -> Optional[str]:
+    """Turn on the persistent compile cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    no directory is set here. Otherwise the cache lives at
+    ``<repo>/.jax_cache`` (git-ignored) — except in a CPU-only process
+    (a rehearsal), which gets none: XLA:CPU warns on every reload of its
+    own entries. Call before the first compile."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    if jax.default_backend() == "cpu":
+        return None
+    jax.config.update("jax_compilation_cache_dir", REPO_CACHE_DIR)
+    return REPO_CACHE_DIR
 
 
 class CompileCounter(logging.Handler):

@@ -112,7 +112,7 @@ def _round_sharded(
     sharded program."""
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.mesh import SEED_AXIS, shard_map_compat
+    from ..parallel.mesh import SEED_AXIS
 
     def device_run(state: EngineState, budget, stop_live):
         def cond(carry):
@@ -133,10 +133,11 @@ def _round_sharded(
         return state
 
     return jax.jit(
-        shard_map_compat(
-            device_run, mesh,
+        jax.shard_map(
+            device_run, mesh=mesh,
             in_specs=(P(SEED_AXIS), P(SEED_AXIS), P(None)),
             out_specs=P(SEED_AXIS),
+            check_vma=False,
         )
     )
 

@@ -158,7 +158,7 @@ def decode_lanes(final, lanes) -> List[History]:
     case), and a sparse one (< a quarter of the lanes — the screened
     case) gathers the suspect rows device-side first, so a 16k-lane
     chunk with a handful of suspects moves kilobytes, not the whole
-    ~100 MB plane, through a possibly-tunneled link."""
+    ~100 MB plane."""
     lanes = [int(lane) for lane in lanes]
     if not lanes:
         return []

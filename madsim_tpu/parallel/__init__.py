@@ -14,7 +14,6 @@ from .mesh import (
     seed_mesh,
     shard_seeds,
     shard_state,
-    shard_map_compat,
     mesh_layout,
     run_sweep_sharded,
     run_sweep_sharded_chunked,
@@ -27,7 +26,6 @@ __all__ = [
     "seed_mesh",
     "shard_seeds",
     "shard_state",
-    "shard_map_compat",
     "mesh_layout",
     "run_sweep_sharded",
     "run_sweep_sharded_chunked",

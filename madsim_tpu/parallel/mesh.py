@@ -28,27 +28,6 @@ from ..engine.core import EngineConfig, EngineState, Workload, init_sweep, step_
 SEED_AXIS = "seeds"
 
 
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` across the jax versions this repo meets: newer
-    releases export it top-level with a ``check_vma`` knob, while 0.4.x
-    only has ``jax.experimental.shard_map.shard_map`` with the older
-    ``check_rep`` spelling. Both checkers are disabled for the same
-    reason (see ``sharded_step``): lax.switch branches mix mesh-constant
-    and mesh-varying outputs, which the replication checker rejects even
-    though the program is replication-safe."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
-
-
 def seed_mesh(devices: Optional[Sequence] = None) -> Mesh:
     """A 1-D mesh over all (or the given) devices, axis ``"seeds"``."""
     import numpy as np
@@ -65,9 +44,12 @@ def shard_seeds(mesh: Mesh, seeds: jnp.ndarray) -> jnp.ndarray:
 
 
 def sharded_step(workload: Workload, cfg: EngineConfig, mesh: Mesh):
-    """Build an explicit n-step sharded step: advances every local seed
-    ``n_steps`` events and returns the global number of still-live seeds
-    via ``psum``.
+    """Build an explicit n-step sharded step, jitted: advances every
+    local seed ``n_steps`` events and returns the global number of
+    still-live seeds via ``psum``. Jitted because an eager ``shard_map``
+    call on a state whose leaves carry mixed shardings (``init_sweep``
+    output: some leaves single-device, some on the mesh) is refused by
+    JAX 0.9 ("Unexpected XLA sharding override").
 
     Kept as the multichip dryrun/CI entry point (__graft_entry__ calls it
     with a fixed n_steps to demonstrate one sharded step + collective);
@@ -91,11 +73,14 @@ def sharded_step(workload: Workload, cfg: EngineConfig, mesh: Mesh):
     # data-dependent one), which the varying-manual-axes checker rejects
     # even though the program is replication-safe (communication happens
     # only in the psum below).
-    return shard_map_compat(
-        local_step,
-        mesh,
-        in_specs=(P(SEED_AXIS), P()),
-        out_specs=(P(SEED_AXIS), P()),
+    return jax.jit(
+        jax.shard_map(
+            local_step,
+            mesh=mesh,
+            in_specs=(P(SEED_AXIS), P()),
+            out_specs=(P(SEED_AXIS), P()),
+            check_vma=False,
+        )
     )
 
 
@@ -122,11 +107,12 @@ def _sharded_run(workload: Workload, cfg: EngineConfig, mesh: Mesh):
         return state
 
     return jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             device_run,
-            mesh,
+            mesh=mesh,
             in_specs=P(SEED_AXIS),
             out_specs=P(SEED_AXIS),
+            check_vma=False,
         )
     )
 

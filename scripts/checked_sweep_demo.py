@@ -38,7 +38,8 @@ driver's — the gate's streaming leg runs 2 processes x 2 drivers and
 diffs all four.
 
 ``--mesh N`` runs the identical pipeline sharded over an N-device mesh
-(re-execing under the forced CPU host mesh when needed) — the report
+(under ``JAX_PLATFORMS=cpu`` it re-execs onto a forced N-device host
+mesh; elsewhere fewer than N devices is an error) — the report
 must be byte-identical to the unsharded one; the determinism gate runs
 this across 2 processes x 2 mesh sizes and diffs all four.
 """
@@ -99,8 +100,11 @@ def main() -> int:
 
         mesh = parallel.seed_mesh(jax.devices()[: args.mesh])
 
+    from madsim_tpu.engine.compiles import use_compile_cache
     from madsim_tpu.models import etcd
     from madsim_tpu.oracle.screen import checked_sweep
+
+    use_compile_cache()
 
     cfg = etcd.EtcdConfig(
         hist_slots=256, bug_stale_read=not args.clean

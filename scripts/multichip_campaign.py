@@ -7,8 +7,9 @@ Two phases, both through the sharded pipelined checked-sweep driver
 1. **curve** — one fixed-spec checked sweep (sweep + on-device screen +
    WGL checking) at each device count in ``--devices``, same seed range,
    compiles excluded; prints aggregate seeds/s, events/s and
-   time-to-first-bug per count and ASSERTS the merged summary bytes are
-   identical across every mesh size (the invariance contract).
+   time-to-first-bug per count and ASSERTS the merged summary bytes,
+   less the chunk-dependent dedup counters, are identical across every
+   mesh size (the invariance contract).
 2. **campaign** — a genuine coverage-guided fault campaign (seeded
    FaultSpec mutations, retain-on-new-bits, election-history screening
    + checking) over ``--campaign-seeds`` total seeds at the largest
@@ -16,11 +17,12 @@ Two phases, both through the sharded pipelined checked-sweep driver
    additionally re-runs a small campaign at two device counts and
    byte-compares the JSONL reports.
 
-Runs anywhere: when the process sees fewer devices than requested it
-re-execs itself under the forced CPU host mesh
-(``madsim_tpu._cpu_mesh_env``), the same environment the multichip
-dryrun gate and the pytest suite use. ``--smoke`` shrinks every knob to
-a ~1-minute CI gate (``make multichip-smoke``).
+Under ``JAX_PLATFORMS=cpu`` it re-execs itself onto a forced CPU host
+mesh of the largest requested size (``madsim_tpu._cpu_mesh_env``), the
+same environment the multichip dryrun gate and the pytest suite use;
+on an accelerator host too few devices is an error, never a CPU run.
+``--smoke`` shrinks every knob to a ~1-minute CI gate (``make
+multichip-smoke``).
 
 Wall-clock metrics go to stdout JSON; the byte-compared artifacts
 (checked-sweep totals, campaign JSONL) never contain times or paths.
@@ -88,6 +90,10 @@ def main() -> int:
 
     import jax
 
+    from madsim_tpu.engine.compiles import use_compile_cache
+
+    use_compile_cache()
+
     from madsim_tpu.explore import (
         CampaignConfig,
         checked_sweep_curve,
@@ -102,7 +108,9 @@ def main() -> int:
     assert curve["bytes_invariant"], (
         "sharded checked-sweep summary bytes differ across mesh sizes"
     )
-    out = {"backend": jax.default_backend(), "curve": curve}
+    dev = jax.devices()[0]
+    out = {"device": {"platform": dev.platform, "kind": dev.device_kind,
+                      "count": len(jax.devices())}, "curve": curve}
 
     if args.campaign_seeds:
         ctarget, cbase = _target("raft", args.smoke)

@@ -32,7 +32,7 @@ import urllib.request
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"  # CPU smoke: never the chip
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402

@@ -37,7 +37,7 @@ def main() -> int:
     ap.add_argument("--shrink-tests", type=int, default=8)
     args = ap.parse_args()
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ["JAX_PLATFORMS"] = "cpu"  # CPU run: never the chip
     import jax.numpy as jnp
     import numpy as np
 

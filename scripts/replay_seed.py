@@ -158,7 +158,7 @@ def main() -> None:
     ap.add_argument("--events", type=int, default=30, help="trace lines to print")
     args = ap.parse_args()
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ["JAX_PLATFORMS"] = "cpu"  # CPU run: never the chip
     if args.model == "etcd":
         if args.volatile:
             ap.error("--volatile is the raft amnesia config (default model)")

@@ -47,7 +47,7 @@ import jax.numpy as jnp
 
 from madsim_tpu import obs
 from madsim_tpu.engine import core
-from madsim_tpu.engine.compiles import count_compiles
+from madsim_tpu.engine.compiles import count_compiles, use_compile_cache
 from madsim_tpu.models import raft
 from madsim_tpu.models._common import merge_summaries
 
@@ -84,6 +84,7 @@ def main() -> None:
                 f"chunk {CHUNK} and total {total} must divide the "
                 f"{n_dev}-device mesh"
             )
+    use_compile_cache()
     cfg = raft.RaftConfig(num_nodes=5, crashes=1)
     ecfg = raft.engine_config(cfg, time_limit_ns=3_000_000_000)
     wl = raft.workload(cfg)
