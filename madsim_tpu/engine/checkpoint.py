@@ -261,9 +261,9 @@ def resume_sweep(
     workload: Workload, cfg: EngineConfig, state: EngineState
 ) -> EngineState:
     """Continue a (possibly restored) sweep until every seed finishes."""
-    from .core import _drive
+    from .core import run_drive
 
-    return _drive(workload, cfg, state)  # shares run_sweep's trace cache
+    return run_drive(workload, cfg, state)  # shares run_sweep's program
 
 
 def _chunk_sha(seeds_host: np.ndarray, lo: int, k: int) -> str:
@@ -562,10 +562,11 @@ def run_sweep_pipelined(
 
     ``telemetry`` (``obs.Telemetry`` or None) records chunk wall time,
     host-phase time, seeds-done progress and skip/resume events, and —
-    when the handle carries a trace — one "device" span per chunk
-    (dispatch -> summary-done) with the previous chunk's "host" flush
-    span nested inside its wall window, which is exactly the overlap
-    picture Perfetto renders. Strictly OUT-OF-BAND: every recorder is
+    when the handle carries a trace — one "dispatch" span per chunk (the
+    host's window from dispatch to summary-done; device time is in the
+    device trace only) with the previous chunk's "host" flush span
+    nested inside it, which is exactly the overlap picture Perfetto
+    renders. Strictly OUT-OF-BAND: every recorder is
     behind an ``is not None`` guard and summaries are never touched, so
     the merged report is byte-identical with telemetry on or off.
     """
@@ -574,7 +575,7 @@ def run_sweep_pipelined(
 
     from .core import (
         _concat_finals, _pad_params, _pad_seeds, _slice_params, run_sweep,
-        _drive,
+        run_drive,
     )
     from ..models._common import merge_summaries  # lazy: models import us
 
@@ -586,7 +587,7 @@ def run_sweep_pipelined(
                 workload, cfg, chunk, params=pchunk
             )
     if resume_chunk is None:
-        resume_chunk = lambda state: _drive(workload, cfg, state)  # noqa: E731
+        resume_chunk = lambda state: run_drive(workload, cfg, state)  # noqa: E731
     seeds = jnp.asarray(seeds, jnp.int64)
     seeds_host = np.asarray(seeds)
     n = int(seeds.shape[0])
@@ -810,8 +811,9 @@ def run_sweep_pipelined(
             )
             if tracer is not None:
                 tracer.complete(
-                    f"device chunk lo={lo}", d0, tracer._now_us() - d0,
-                    track="device", args={"lo": lo, "k": k},
+                    f"chunk lo={lo} dispatch-to-summary", d0,
+                    tracer._now_us() - d0,
+                    track="dispatch", args={"lo": lo, "k": k},
                 )
         pending = (lo, k, sha, final, susp, summary, path)
         computed += 1

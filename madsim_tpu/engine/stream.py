@@ -286,8 +286,9 @@ def stream_sweep(
     - ``telemetry`` (``obs.Telemetry`` or None): per-round occupancy and
       queue-depth gauges, round/refill-quorum/flush latency histograms,
       retirement-flux and drain-tail counters, seeds-done progress, and
-      — when the handle carries a trace — "device" round spans with
-      "host" flush spans interleaved plus an occupancy counter track
+      — when the handle carries a trace — "dispatch" round spans (the
+      host's window from dispatch to the pool state) with "host" flush
+      spans interleaved plus an occupancy counter track
       (the refill-cadence picture). Strictly OUT-OF-BAND: every recorder
       is behind an ``is not None`` guard; the report bytes are identical
       with telemetry on or off.
@@ -906,8 +907,8 @@ def stream_sweep(
             telemetry.count("stream_rounds_total")
             if tracer is not None:
                 tracer.complete(
-                    f"round {rounds}", r0, tracer._now_us() - r0,
-                    track="device",
+                    f"round {rounds} dispatch-to-state", r0,
+                    tracer._now_us() - r0, track="dispatch",
                     args={"occupancy": assigned / L, "queue": n - next_q},
                 )
         ctr = np.asarray(state.ctr)

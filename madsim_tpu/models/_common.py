@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..engine.core import Emits
 
 # sentinel for an unused extra slot
@@ -186,22 +187,26 @@ def make_sweep_summary(
         first ``k`` lanes — the padded-ragged-chunk path: the masked
         variant is ONE compiled program for all ``k``, so a ragged
         final chunk costs no recompile (engine/checkpoint.py drivers
-        and scripts/sweep_million.py rely on this)."""
-        if limit is None:
-            vec, union, emix = _summarize(final)
-            seeds = int(final.seed.shape[0])
-        else:
-            vec, union, emix = _summarize_limit(
-                final, jnp.asarray(limit, jnp.int32)
-            )
-            seeds = int(limit)
-        vec = np.asarray(vec)
-        out = {"seeds": seeds}
-        out.update((n, int(v)) for n, v in zip(names, vec))
-        if union.shape[0]:
-            out["coverage_map"] = [int(w) for w in np.asarray(union)]
-        if emix.shape[0]:
-            out["event_mix"] = [int(v) for v in np.asarray(emix)]
+        and scripts/sweep_million.py rely on this). The call is one
+        ``madsim.summary`` program span, its blocking readback a
+        ``madsim.summary.wait`` span inside it (``obs.span``; ``lo`` is
+        the first lane summed)."""
+        with obs.span("madsim.summary", lo=0):
+            if limit is None:
+                vec, union, emix = _summarize(final)
+                seeds = int(final.seed.shape[0])
+            else:
+                vec, union, emix = _summarize_limit(
+                    final, jnp.asarray(limit, jnp.int32)
+                )
+                seeds = int(limit)
+            with obs.span("madsim.summary.wait", lo=0):
+                out = {"seeds": seeds}
+                out.update((n, int(v)) for n, v in zip(names, np.asarray(vec)))
+                if union.shape[0]:
+                    out["coverage_map"] = [int(w) for w in np.asarray(union)]
+                if emix.shape[0]:
+                    out["event_mix"] = [int(v) for v in np.asarray(emix)]
         return out
 
     # the chunk drivers key program-reuse decisions on this marker

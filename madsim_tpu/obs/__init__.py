@@ -14,9 +14,11 @@ bit-identical with telemetry on or off — the determinism gate pins it):
   and a run ID;
 - **exposition** (obs/export.py): Prometheus text format, served by an
   opt-in localhost HTTP endpoint;
-- **trace spans** (tracing.SpanTracer): driver phases as one Chrome/
-  Perfetto file — device sweep of chunk N over host check of chunk N−1,
-  stream round/refill cadence, checker-pool fan-out.
+- **trace spans** (``obs.span``, tracing.SpanTracer): program spans as
+  ``jax.profiler`` annotations on the device trace's clock, and driver
+  phases as one Chrome/Perfetto file — the dispatch-to-summary window of
+  chunk N over the host check of chunk N−1, stream round/refill cadence,
+  checker-pool fan-out.
 
 Drivers take ``telemetry=`` (a :class:`Telemetry` or None); None means
 ZERO instrumentation work on the hot path — the baseline the bench
@@ -28,7 +30,7 @@ from __future__ import annotations
 
 import sys
 import time
-from contextlib import nullcontext
+from contextlib import contextmanager
 from typing import Optional
 
 from .journal import (  # noqa: F401
@@ -119,11 +121,9 @@ class Telemetry:
             self.journal.write(kind, **fields)
 
     def span(self, name: str, track: str = "host", **args):
-        """Context manager: a driver-phase span on the trace (no-op
-        without a trace path)."""
-        if self.tracer is None:
-            return nullcontext()
-        return self.tracer.span(name, track=track, args=args or None)
+        """Context manager: a program span (``obs.span``) recorded on
+        ``track`` of this handle's trace, if it has one."""
+        return span(name, self, track=track, **args)
 
     def sample(self, name: str, **values) -> None:
         """One counter-series sample on the trace timeline (occupancy,
@@ -159,6 +159,25 @@ class Telemetry:
 
     def __exit__(self, *exc) -> None:
         self.close()
+
+
+@contextmanager
+def span(name: str, telemetry: Optional[Telemetry] = None,
+         track: str = "host", **args):
+    """A program span: the wrapped block enters a
+    ``jax.profiler.TraceAnnotation`` carrying ``args``, so a profiler
+    trace holds it in its host plane on the device ops' clock (and costs
+    next to nothing when no trace is being taken); with a ``telemetry``
+    that records a trace, the block is also a ``SpanTracer`` span on
+    ``track``, on the same epoch clock."""
+    from jax.profiler import TraceAnnotation  # lazy: obs stays JAX-free
+
+    with TraceAnnotation(name, **args):
+        if telemetry is None or telemetry.tracer is None:
+            yield
+        else:
+            with telemetry.tracer.span(name, track=track, args=args or None):
+                yield
 
 
 def _fmt_eta(seconds: float) -> str:

@@ -44,15 +44,23 @@ def _label_key(labelnames: Tuple[str, ...], labels: dict) -> Tuple[str, ...]:
 
 
 class Counter:
-    """Monotonic counter; ``inc`` only (a decrement is a bug upstream)."""
+    """Monotonic counter; ``inc`` only (a decrement is a bug upstream).
+
+    ``inc_deferred`` adds a value the device is still computing without
+    waiting for it; such values are folded in when the counter is read."""
 
     kind = "counter"
+    # deferred values a counter holds before it folds in those already
+    # computed: bounds what a days-long run keeps (a benchmark window of
+    # about a hundred drives never reaches it)
+    MAX_PENDING = 256
 
     def __init__(self, name: str, help: str = "", labels: Tuple[str, ...] = ()):
         self.name = name
         self.help = help
         self.labelnames = tuple(labels)
         self._values: Dict[Tuple[str, ...], float] = {}
+        self._pending: list = []  # (key, deferred value, scale)
         self._lock = threading.Lock()
 
     def inc(self, value: float = 1, **labels) -> None:
@@ -62,12 +70,40 @@ class Counter:
         with self._lock:
             self._values[key] = self._values.get(key, 0) + value
 
+    def inc_deferred(self, value, scale: float = 1, **labels) -> None:
+        """Add ``scale * value``, where ``value`` is a device scalar that
+        may not be computed yet (it has ``is_ready()`` and ``int()``, as a
+        ``jax.Array`` has). Never waits on it; it must not be negative."""
+        key = _label_key(self.labelnames, labels)
+        with self._lock:
+            self._pending.append((key, value, scale))
+            over = len(self._pending) > self.MAX_PENDING
+        if over:
+            self._fold(ready_only=True)
+
+    def _fold(self, ready_only: bool = False) -> None:
+        """Fold deferred values into the counts; with ``ready_only``, only
+        the leading ones already computed. They are read outside the
+        lock, so a reader waiting on the device holds up no writer."""
+        with self._lock:
+            n = len(self._pending)
+            if ready_only:
+                n = next((i for i, (_k, v, _s) in enumerate(self._pending)
+                          if not v.is_ready()), n)
+            taken, self._pending[:n] = self._pending[:n], []
+        folded = [(key, int(value) * scale) for key, value, scale in taken]
+        with self._lock:
+            for key, v in folded:
+                self._values[key] = self._values.get(key, 0) + v
+
     def get(self, **labels) -> float:
         key = _label_key(self.labelnames, labels)
+        self._fold()
         with self._lock:
             return self._values.get(key, 0)
 
     def series(self) -> List[Tuple[Tuple[str, ...], float]]:
+        self._fold()
         with self._lock:
             return sorted(self._values.items())
 

@@ -13,11 +13,12 @@ practical way to *see* a schedule when debugging a failing seed.
 
 ``SpanTracer`` scales the same exporter from one seed's polls to the
 FLEET drivers (madsim_tpu/obs): wall-clock phase spans on named tracks
-("device", "host", "stream", "checkers"), so one trace file shows the
-device sweep of chunk N overlapping the host decode/check of chunk N−1,
-the stream pool's round/refill cadence, and the checker-pool fan-out.
-Same JSON shape, same viewers; only the clock differs (virtual ns for
-``Tracer``, wall µs since construction for ``SpanTracer``).
+("dispatch", "host"), so one trace file shows the dispatch-to-summary
+window of chunk N overlapping the host decode/check of chunk N−1, the
+stream pool's round/refill cadence, and the checker-pool fan-out. Same JSON shape, same viewers; only the clock differs (virtual
+ns for ``Tracer``; for ``SpanTracer`` the epoch clock a ``jax.profiler``
+trace stamps its host events with, so its spans lie against the device
+trace's).
 """
 
 from __future__ import annotations
@@ -124,10 +125,12 @@ class SpanTracer:
     like pool occupancy — the fleet-scale sibling of :class:`Tracer`.
 
     Tracks are lazily numbered in first-use order and named through "M"
-    ``thread_name`` metadata, so Perfetto shows "device" / "host" /
-    "stream" rows instead of bare thread ids. Timestamps are wall
-    microseconds since construction (Chrome's unit). Thread-safe: the
-    checker pool and the HTTP exporter may emit concurrently.
+    ``thread_name`` metadata, so Perfetto shows "dispatch" / "host" rows
+    instead of bare thread ids. Timestamps are epoch
+    microseconds (Chrome's unit) from ``time.time_ns``, the clock a
+    ``jax.profiler`` trace's host plane uses (its session start plus
+    each event's offset). Thread-safe: the checker pool and the HTTP
+    exporter may emit concurrently.
     """
 
     PID = 0  # one logical process: the driver
@@ -141,12 +144,11 @@ class SpanTracer:
                 "args": {"name": "madsim_tpu driver"},
             }
         ]
-        self._t0 = _walltime.perf_counter_ns()
         self._tracks: Dict[str, int] = {}
         self._lock = threading.Lock()
 
     def _now_us(self) -> float:
-        return (_walltime.perf_counter_ns() - self._t0) / 1000.0
+        return _walltime.time_ns() / 1000.0
 
     def _tid(self, track: str) -> int:
         tid = self._tracks.get(track)
@@ -172,7 +174,7 @@ class SpanTracer:
         cat: str = "phase",
         args: Optional[dict] = None,
     ) -> None:
-        """One finished span from precomputed times (µs since t0)."""
+        """One finished span from precomputed times (epoch µs)."""
         with self._lock:
             ev = {
                 "name": name,

@@ -64,7 +64,7 @@ def main() -> None:
 
     results = []
     for S in BATCHES:
-        xla = lambda s0: jax.block_until_ready(core._drive(wl, cfg, s0))  # noqa: E731
+        xla = lambda s0: jax.block_until_ready(core.run_drive(wl, cfg, s0))  # noqa: E731
 
         # one fixed verification batch per size: EVERY tile that gets
         # timed must first reproduce the XLA driver's final state
@@ -72,7 +72,7 @@ def main() -> None:
         # publish a timing as verified); the comparison doubles as the
         # warmup/compile call
         s_verify = core._init(wl, cfg, fresh_seeds(S))
-        ref = core._drive(wl, cfg, s_verify)
+        ref = core.run_drive(wl, cfg, s_verify)
 
         # contenders, then INTERLEAVED reps, so a slow stretch of the
         # machine hits every contender alike (min-of-reps)

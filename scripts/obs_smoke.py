@@ -7,11 +7,11 @@ trace file as the run's artifact:
 1. out-of-band: the pipelined checked sweep and the streaming checked
    sweep each produce byte-equal report dicts with telemetry on vs off
    (the process-level byte diff lives in scripts/check_determinism.sh);
-2. trace spans: the saved Chrome-trace JSON has named "device" and
-   "host" tracks, the device sweep of chunk N visibly OVERLAPS the host
-   decode/check of chunk N-1 (interval intersection asserted), and the
-   stream pool's occupancy rides along as counter samples (the refill
-   cadence view);
+2. trace spans: the saved Chrome-trace JSON has named "dispatch" and
+   "host" tracks, the dispatch-to-summary window of chunk N visibly
+   OVERLAPS the host decode/check of chunk N-1 (interval intersection
+   asserted), and the stream pool's occupancy rides along as counter
+   samples (the refill cadence view);
 3. journal: the run's JSONL stream has run_start/run_end plus per-chunk
    and per-flush events, all carrying the same run ID;
 4. exposition: the opt-in localhost HTTP endpoint serves the registry
@@ -138,24 +138,24 @@ def main() -> int:
         for e in events
         if e.get("ph") == "M" and e.get("name") == "thread_name"
     }
-    assert "device" in tracks and "host" in tracks, f"tracks: {tracks}"
-    dev = _spans(events, tracks["device"])
+    assert "dispatch" in tracks and "host" in tracks, f"tracks: {tracks}"
+    dev = _spans(events, tracks["dispatch"])
     host = _spans(events, tracks["host"])
-    assert dev and host, f"empty tracks: {len(dev)} device, {len(host)} host"
+    assert dev and host, f"empty tracks: {len(dev)} dispatch, {len(host)} host"
     overlapped = sum(
         1 for h in host if any(_overlaps(h, d) for d in dev)
     )
-    assert overlapped > 0, "no device/host phase overlap visible in trace"
+    assert overlapped > 0, "no dispatch/host phase overlap visible in trace"
     occ_samples = [
         e for e in events
         if e.get("ph") == "C" and e.get("name") == "stream occupancy"
     ]
     assert len(occ_samples) >= 2, "no refill-cadence counter samples"
     rounds = [e for e in dev if e["name"].startswith("round ")]
-    assert rounds, "no stream round spans on the device track"
+    assert rounds, "no stream round spans on the dispatch track"
     print(
-        f"trace: OK ({len(dev)} device spans, {len(host)} host spans, "
-        f"{overlapped} host spans overlap device work, "
+        f"trace: OK ({len(dev)} dispatch spans, {len(host)} host spans, "
+        f"{overlapped} host spans overlap a dispatch window, "
         f"{len(occ_samples)} occupancy samples) -> {trace_path}"
     )
 

@@ -59,7 +59,7 @@ def test_megakernel_bit_exact_vs_xla(steps, tile):
     cfg = mk.probe_config(max_steps=steps)
     seeds = jnp.arange(16, dtype=jnp.int64)
     s0 = core._init(wl, cfg, seeds)
-    ref = core._drive(wl, cfg, s0)
+    ref = core.run_drive(wl, cfg, s0)
     got = mk.run_megasweep(
         s0, steps=steps, time_limit=cfg.time_limit_ns, tile=tile,
         interpret=True,
@@ -78,7 +78,7 @@ def test_megakernel_time_limit_semantics():
                             max_steps=steps)
     seeds = jnp.arange(8, dtype=jnp.int64)
     s0 = core._init(wl, cfg, seeds)
-    ref = core._drive(wl, cfg, s0)
+    ref = core.run_drive(wl, cfg, s0)
     got = mk.run_megasweep(
         s0, steps=steps, time_limit=cfg.time_limit_ns, tile=8,
         interpret=True,

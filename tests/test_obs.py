@@ -206,7 +206,7 @@ def test_telemetry_recorders(tmp_path):
     t.gauge("occupancy", 0.9)
     t.observe("chunk_seconds", 0.5)
     t.event("chunk", lo=0)
-    with t.span("phase", track="device", lo=0):
+    with t.span("phase", track="dispatch", lo=0):
         pass
     t.sample("occupancy", pool=0.9)
     t.event_mix({"event_mix": [3, 0, 7]})
@@ -333,10 +333,11 @@ def test_tracer_golden_shape(tmp_path):
 
 def test_span_tracer_golden_shape(tmp_path):
     st = tracing.SpanTracer()
-    with st.span("device chunk lo=0", track="device", args={"k": 32}):
+    with st.span("chunk lo=0 dispatch-to-summary", track="dispatch",
+                 args={"k": 32}):
         with st.span("host flush lo=0", track="host"):
             pass
-    st.complete("round 1", 10.0, 5.0, track="device")
+    st.complete("round 1 dispatch-to-state", 10.0, 5.0, track="dispatch")
     st.instant("snapshot", track="host")
     st.counter("stream occupancy", occupancy=0.875, queue=96)
     path = tmp_path / "spans.json"
@@ -346,21 +347,22 @@ def test_span_tracer_golden_shape(tmp_path):
     _check_shape(events)
     # named tracks announced via thread_name metadata (numbered in
     # first-RECORD order: the nested host span completes before the
-    # device span that encloses it)
+    # dispatch span that encloses it)
     tracks = {
         e["args"]["name"]: e["tid"]
         for e in events
         if e["ph"] == "M" and e["name"] == "thread_name"
     }
-    assert set(tracks) == {"device", "host"}
+    assert set(tracks) == {"dispatch", "host"}
     by_name = {e["name"]: e for e in events if e["ph"] == "X"}
-    assert by_name["device chunk lo=0"]["tid"] == tracks["device"]
+    chunk = "chunk lo=0 dispatch-to-summary"
+    assert by_name[chunk]["tid"] == tracks["dispatch"]
     assert by_name["host flush lo=0"]["tid"] == tracks["host"]
-    assert by_name["device chunk lo=0"]["args"] == {"k": 32}
-    assert by_name["round 1"]["ts"] == 10.0
-    assert by_name["round 1"]["dur"] == 5.0
-    # the nested host span's window sits inside the device span's
-    dev, host = by_name["device chunk lo=0"], by_name["host flush lo=0"]
+    assert by_name[chunk]["args"] == {"k": 32}
+    assert by_name["round 1 dispatch-to-state"]["ts"] == 10.0
+    assert by_name["round 1 dispatch-to-state"]["dur"] == 5.0
+    # the nested host span's window sits inside the dispatch span's
+    dev, host = by_name[chunk], by_name["host flush lo=0"]
     assert dev["ts"] <= host["ts"]
     assert host["ts"] + host["dur"] <= dev["ts"] + dev["dur"] + 1e-6
     (c,) = [e for e in events if e["ph"] == "C"]
