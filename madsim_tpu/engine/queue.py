@@ -16,6 +16,12 @@ which on TPU run ~6-10x slower than the masked equivalents (see
 engine/ops.py). For Q ≲ 256 each op is a handful of VPU lanes, far cheaper
 than the host round-trip it replaces.
 
+The one prefix sum, ``push_many``'s rank among free slots, runs on the MXU
+as a matmul with a constant upper-triangular 0/1 matrix. ``jnp.cumsum``
+lowers on TPU to a whole-axis ``reduce_window`` that XLA expands into an
+O(Q²) sum on the VPU; the matmul does the same O(Q²) work on the unit
+built for it, and is exact (see ``_free_count``).
+
 Occupancy is encoded in the time plane itself: a slot is free iff its time
 is ``INVALID_TIME`` (every constructor and removal maintains this), so no
 separate validity plane travels in the loop carry. The pre-round-5 layout
@@ -39,6 +45,7 @@ from __future__ import annotations
 from typing import NamedTuple, Tuple, Union
 
 import jax.numpy as jnp
+import numpy as np
 
 from .ops import onehot
 
@@ -121,6 +128,22 @@ def push(
     )
 
 
+def _free_count(free: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive prefix count of a bool[Q] mask: ``count[j]`` is the
+    number of free slots at index <= j, as int32.
+
+    ``free (0/1) @ tri`` with ``tri[i, j] = 1`` for ``i <= j`` — a matmul
+    of ``[lanes, Q] x [Q, Q]`` once vmapped, with the triangle a
+    compile-time constant. Exact: both factors are 0/1, so bf16 holds
+    them exactly, every product is 0 or 1, and no sum exceeds Q, which
+    the float32 accumulator holds exactly for any Q below 2**24.
+    """
+    Q = free.shape[0]
+    tri = np.triu(np.ones((Q, Q), dtype=jnp.bfloat16))
+    count = jnp.dot(free.astype(jnp.bfloat16), tri, preferred_element_type=jnp.float32)
+    return count.astype(jnp.int32)
+
+
 def push_many(
     q: AnyQueue,
     times: jnp.ndarray,  # int64[E]
@@ -130,19 +153,22 @@ def push_many(
 ) -> Tuple[AnyQueue, jnp.ndarray]:
     """Insert up to E events in ONE dense pass: emit ``e`` maps to the
     e-th free slot (ascending index — the same assignment a sequential
-    first-free scan would make), computed via a cumsum rank over the free
-    mask and written with masked selects. No sort, no top_k, no scatter.
+    first-free scan would make), whether or not earlier emits are enabled.
+    The slot's rank among free slots is a prefix count of the free mask,
+    done as one matmul on the MXU (``_free_count``); the events are written
+    with masked selects. No sort, no top_k, no scatter.
     """
     E = times.shape[0]
     free = _free(q)
-    rank = jnp.cumsum(free.astype(jnp.int32)) - 1  # rank among free slots
+    count = _free_count(free)
+    rank = count - 1  # rank among free slots
     eidx = jnp.arange(E, dtype=jnp.int32)
     sel = free[:, None] & (rank[:, None] == eidx[None, :]) & enables[None, :]  # [Q,E]
     write = jnp.any(sel, axis=1)
     t_new = jnp.sum(jnp.where(sel, times[None, :], jnp.int64(0)), axis=1, dtype=jnp.int64)
     k_new = jnp.sum(jnp.where(sel, kinds[None, :], 0), axis=1, dtype=jnp.int32)
     p_new = jnp.sum(jnp.where(sel[:, :, None], pays[None, :, :], 0), axis=1, dtype=jnp.int32)
-    num_free = jnp.sum(free.astype(jnp.int32))
+    num_free = count[-1]
     overflow = jnp.any(enables & (eidx >= num_free))
     return (
         _rebuild(

@@ -61,6 +61,89 @@ def test_queue_disabled_push_is_noop():
     assert int(equeue.size(q)) == 0
 
 
+def _first_free_push(time, kind, pay, times, kinds, pays, enables):
+    """One lane of ``push_many`` as a plain loop: emit ``e`` goes to the
+    e-th free slot whether or not earlier emits are enabled (the rule of
+    ``benchmark/reference/engine.py``'s ``push``)."""
+    time, kind, pay = list(time), list(kind), [list(p) for p in pay]
+    free = [i for i, t in enumerate(time) if t == equeue.INVALID_TIME]
+    overflow = False
+    for e, on in enumerate(enables):
+        if not on:
+            continue
+        if e >= len(free):
+            overflow = True
+            continue
+        s = free[e]
+        time[s], kind[s], pay[s] = times[e], kinds[e], list(pays[e])
+    return time, kind, pay, overflow
+
+
+@pytest.mark.parametrize("legacy", [False, True], ids=["packed", "legacy"])
+@pytest.mark.parametrize("capacity", [7, 60, 64, 129, 256])
+def test_push_many_matches_first_free_loop(capacity, legacy):
+    """``push_many`` under vmap against the plain first-free loop: same
+    planes, same overflow flag, on random free masks (lane 0 full, lane 1
+    empty), random enables, and E reaching past the free count."""
+    lanes, slots, n_emit = 64, 3, capacity // 4 + 3
+    rng = np.random.default_rng(capacity)
+    occupied = rng.random((lanes, capacity)) < rng.random((lanes, 1))
+    occupied[0], occupied[1] = True, False
+    time = np.where(occupied, rng.integers(0, 1 << 40, (lanes, capacity)), equeue.INVALID_TIME)
+    kind = rng.integers(-99, 99, (lanes, capacity)).astype(np.int32)
+    pay = rng.integers(-99, 99, (lanes, capacity, slots)).astype(np.int32)
+    times = rng.integers(0, 1 << 40, (lanes, n_emit))
+    kinds = rng.integers(-99, 99, (lanes, n_emit)).astype(np.int32)
+    pays = rng.integers(-99, 99, (lanes, n_emit, slots)).astype(np.int32)
+    enables = rng.random((lanes, n_emit)) < 0.7
+    enables[2] = True
+
+    q = equeue.EventQueue(jnp.asarray(time), jnp.asarray(kind), jnp.asarray(pay))
+    if legacy:
+        q = equeue.LegacyEventQueue(*q, jnp.asarray(occupied))
+    got, overflow = jax.jit(jax.vmap(equeue.push_many))(
+        q, jnp.asarray(times), jnp.asarray(kinds), jnp.asarray(pays), jnp.asarray(enables)
+    )
+    got_time, got_kind, got_pay, overflow = map(np.asarray, (got.time, got.kind, got.pay, overflow))
+    for i in range(lanes):
+        want = _first_free_push(time[i], kind[i], pay[i], times[i], kinds[i], pays[i], enables[i])
+        assert got_time[i].tolist() == want[0], i
+        assert got_kind[i].tolist() == want[1], i
+        assert got_pay[i].tolist() == want[2], i
+        assert bool(overflow[i]) == want[3], i
+        if legacy:
+            assert (np.asarray(got.valid[i]) == (got_time[i] != equeue.INVALID_TIME)).all(), i
+    assert overflow[0] and not overflow[1]  # the cases at both ends were drawn
+
+
+def _primitive_names(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                if hasattr(sub, "eqns"):
+                    yield from _primitive_names(sub)
+                elif hasattr(sub, "jaxpr") and hasattr(sub.jaxpr, "eqns"):
+                    yield from _primitive_names(sub.jaxpr)
+
+
+def test_push_many_rank_has_no_cumsum():
+    """The free-slot rank is a matmul: a ``cumsum`` lowers on TPU to a
+    whole-axis reduce_window, an O(Q²) sum on the vector unit."""
+    q = equeue.make(60, 8)
+    emits = (
+        jnp.zeros((16, 7), jnp.int64),
+        jnp.zeros((16, 7), jnp.int32),
+        jnp.zeros((16, 7, 8), jnp.int32),
+        jnp.ones((16, 7), bool),
+    )
+    lanes = jax.tree.map(lambda a: jnp.broadcast_to(a, (16, *a.shape)), q)
+    jaxpr = jax.make_jaxpr(jax.vmap(equeue.push_many))(lanes, *emits)
+    names = set(_primitive_names(jaxpr.jaxpr))
+    assert "dot_general" in names
+    assert not {n for n in names if n.startswith("cum") or "reduce_window" in n}, names
+
+
 # -- rng -------------------------------------------------------------------
 
 
