@@ -10,10 +10,11 @@ slot table per seed —
     kind  : int32[Q]   event discriminant (workload-defined)
     pay   : int32[Q,P] payload slots
 
-``pop_min`` = min + one-hot invalidate; ``push_many`` = rank-select masked
-writes. Everything is dense vector code — **no dynamic scatter or gather**,
-which on TPU run ~6-10x slower than the masked equivalents (see
-engine/ops.py). For Q ≲ 256 each op is a handful of VPU lanes, far cheaper
+``pop_min`` = min + one-hot invalidate; ``push_many`` = one masked select
+per emit, each slot taking the emit whose index is its rank among free
+slots, with nothing summed. Everything is dense vector code — **no
+dynamic scatter or gather**, which on TPU run ~6-10x slower than the
+masked equivalents (see engine/ops.py). For Q ≲ 256 each op is a handful of VPU lanes, far cheaper
 than the host round-trip it replaces.
 
 The one prefix sum, ``push_many``'s rank among free slots, runs on the MXU
@@ -155,31 +156,34 @@ def push_many(
     e-th free slot (ascending index — the same assignment a sequential
     first-free scan would make), whether or not earlier emits are enabled.
     The slot's rank among free slots is a prefix count of the free mask,
-    done as one matmul on the MXU (``_free_count``); the events are written
-    with masked selects. No sort, no top_k, no scatter.
+    done as one matmul on the MXU (``_free_count``).
+
+    Slot ``q`` takes at most one emit, the one whose index is its rank
+    (``er``, set to E on an occupied slot so that it matches no emit).
+    So each emit is written with one masked select per plane — an
+    unrolled loop over the static E, which XLA fuses into one pass per
+    plane — straight into the queue planes: no ``[Q, E]`` one-hot and
+    no sum over E. No sort, no top_k, no scatter.
+
+    Emit ``e``'s payload is the row ``pays[e]``. Slicing ``pays``
+    flattened instead makes push's slice cheaper on a v5e but changes
+    the layout in which the handler builds ``pays``, which costs the
+    handler more than push saves (raft, P = 8).
     """
     E = times.shape[0]
     free = _free(q)
     count = _free_count(free)
-    rank = count - 1  # rank among free slots
-    eidx = jnp.arange(E, dtype=jnp.int32)
-    sel = free[:, None] & (rank[:, None] == eidx[None, :]) & enables[None, :]  # [Q,E]
-    write = jnp.any(sel, axis=1)
-    t_new = jnp.sum(jnp.where(sel, times[None, :], jnp.int64(0)), axis=1, dtype=jnp.int64)
-    k_new = jnp.sum(jnp.where(sel, kinds[None, :], 0), axis=1, dtype=jnp.int32)
-    p_new = jnp.sum(jnp.where(sel[:, :, None], pays[None, :, :], 0), axis=1, dtype=jnp.int32)
-    num_free = count[-1]
-    overflow = jnp.any(enables & (eidx >= num_free))
-    return (
-        _rebuild(
-            q,
-            jnp.where(write, t_new, q.time),
-            jnp.where(write, k_new, q.kind),
-            jnp.where(write[:, None], p_new, q.pay),
-            occupy=write,
-        ),
-        overflow,
-    )
+    er = jnp.where(free, count - 1, E)
+    time, kind, pay = q.time, q.kind, q.pay
+    write = jnp.zeros_like(free)
+    for e in range(E):
+        m = (er == e) & enables[e]
+        time = jnp.where(m, times[e], time)
+        kind = jnp.where(m, kinds[e], kind)
+        pay = jnp.where(m[:, None], pays[e], pay)
+        write = write | m
+    overflow = jnp.any(enables & (jnp.arange(E) >= count[-1]))
+    return _rebuild(q, time, kind, pay, occupy=write), overflow
 
 
 def pop_min(

@@ -79,14 +79,24 @@ def _first_free_push(time, kind, pay, times, kinds, pays, enables):
     return time, kind, pay, overflow
 
 
+_PUSH_CASES = [(c, e) for c in (7, 60, 64, 129, 256) for e in ("wide", 1, 7, 15, "off")]
+
+
 @pytest.mark.parametrize("legacy", [False, True], ids=["packed", "legacy"])
-@pytest.mark.parametrize("capacity", [7, 60, 64, 129, 256])
-def test_push_many_matches_first_free_loop(capacity, legacy):
+@pytest.mark.parametrize(
+    "capacity,emits",
+    _PUSH_CASES,
+    ids=[str(c) if e == "wide" else f"{c}-e{e}" for c, e in _PUSH_CASES],
+)
+def test_push_many_matches_first_free_loop(capacity, emits, legacy):
     """``push_many`` under vmap against the plain first-free loop: same
     planes, same overflow flag, on random free masks (lane 0 full, lane 1
-    empty), random enables, and E reaching past the free count."""
-    lanes, slots, n_emit = 64, 3, capacity // 4 + 3
-    rng = np.random.default_rng(capacity)
+    empty) and random enables (lane 2 all on). E is one emit, raft's step
+    (7) and init (15) widths, or "wide", ``capacity // 4 + 3``, reaching
+    past the free count; "off" is the wide E with every emit disabled."""
+    n_emit = emits if isinstance(emits, int) else capacity // 4 + 3
+    lanes, slots = 64, 3
+    rng = np.random.default_rng(capacity * 100 + n_emit)
     occupied = rng.random((lanes, capacity)) < rng.random((lanes, 1))
     occupied[0], occupied[1] = True, False
     time = np.where(occupied, rng.integers(0, 1 << 40, (lanes, capacity)), equeue.INVALID_TIME)
@@ -96,7 +106,9 @@ def test_push_many_matches_first_free_loop(capacity, legacy):
     kinds = rng.integers(-99, 99, (lanes, n_emit)).astype(np.int32)
     pays = rng.integers(-99, 99, (lanes, n_emit, slots)).astype(np.int32)
     enables = rng.random((lanes, n_emit)) < 0.7
-    enables[2] = True
+    enables[0, 0] = enables[2] = True
+    if emits == "off":
+        enables[:] = False
 
     q = equeue.EventQueue(jnp.asarray(time), jnp.asarray(kind), jnp.asarray(pay))
     if legacy:
@@ -113,35 +125,59 @@ def test_push_many_matches_first_free_loop(capacity, legacy):
         assert bool(overflow[i]) == want[3], i
         if legacy:
             assert (np.asarray(got.valid[i]) == (got_time[i] != equeue.INVALID_TIME)).all(), i
-    assert overflow[0] and not overflow[1]  # the cases at both ends were drawn
+    # the cases at both ends were drawn: a full lane, an empty one
+    assert overflow[0] == (emits != "off")
+    assert overflow[1] == enables[1, capacity:].any()
 
 
-def _primitive_names(jaxpr):
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
     for eqn in jaxpr.eqns:
-        yield eqn.primitive.name
+        yield eqn
         for param in eqn.params.values():
             for sub in param if isinstance(param, (tuple, list)) else (param,):
                 if hasattr(sub, "eqns"):
-                    yield from _primitive_names(sub)
+                    yield from _eqns(sub)
                 elif hasattr(sub, "jaxpr") and hasattr(sub.jaxpr, "eqns"):
-                    yield from _primitive_names(sub.jaxpr)
+                    yield from _eqns(sub.jaxpr)
+
+
+def _push_many_jaxpr(capacity=60, n_emit=7, slots=8, lanes=16):
+    q = equeue.make(capacity, slots)
+    emits = (
+        jnp.zeros((lanes, n_emit), jnp.int64),
+        jnp.zeros((lanes, n_emit), jnp.int32),
+        jnp.zeros((lanes, n_emit, slots), jnp.int32),
+        jnp.ones((lanes, n_emit), bool),
+    )
+    planes = jax.tree.map(lambda a: jnp.broadcast_to(a, (lanes, *a.shape)), q)
+    return jax.make_jaxpr(jax.vmap(equeue.push_many))(planes, *emits).jaxpr
 
 
 def test_push_many_rank_has_no_cumsum():
     """The free-slot rank is a matmul: a ``cumsum`` lowers on TPU to a
     whole-axis reduce_window, an O(Q²) sum on the vector unit."""
-    q = equeue.make(60, 8)
-    emits = (
-        jnp.zeros((16, 7), jnp.int64),
-        jnp.zeros((16, 7), jnp.int32),
-        jnp.zeros((16, 7, 8), jnp.int32),
-        jnp.ones((16, 7), bool),
-    )
-    lanes = jax.tree.map(lambda a: jnp.broadcast_to(a, (16, *a.shape)), q)
-    jaxpr = jax.make_jaxpr(jax.vmap(equeue.push_many))(lanes, *emits)
-    names = set(_primitive_names(jaxpr.jaxpr))
+    names = {eqn.primitive.name for eqn in _eqns(_push_many_jaxpr())}
     assert "dot_general" in names
     assert not {n for n in names if n.startswith("cum") or "reduce_window" in n}, names
+
+
+def test_push_many_picks_values_without_slot_by_emit_plane():
+    """Each slot takes at most one emit, so the values are written with one
+    masked select per emit: no array carries both the slot (Q = 60) and
+    emit (E = 7) axes — the one-hot pick and its layout copy — and no
+    int64 sum over E, which the TPU emulates with carries."""
+    eqns = list(_eqns(_push_many_jaxpr(capacity=60, n_emit=7)))
+    both = [
+        e for e in eqns for v in e.outvars
+        if {60, 7} <= set(getattr(v.aval, "shape", ()))
+    ]
+    assert not both, both
+    sums = [
+        e for e in eqns
+        if e.primitive.name == "reduce_sum" and e.invars[0].aval.dtype == jnp.int64
+    ]
+    assert not sums, sums
 
 
 # -- rng -------------------------------------------------------------------
