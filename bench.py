@@ -29,9 +29,8 @@ crash/restart fault injection, 3 virtual seconds per seed), with:
 Timing methodology per docs/pallas_finding.md §0: fresh seed ranges per
 timed run (no timed run repeats an input), a scalar host readback to
 bound completion, and every timed figure is the MIN of ``REPS``
-interleaved repetitions (rep-outer, case-inner, exactly like
-scripts/bench_megakernel.py), with the max-over-min spread reported per
-point. The headline ``value`` is the chunked 131k sweep (the production
+interleaved repetitions (rep-outer, case-inner), with the max-over-min
+spread reported per point. The headline ``value`` is the chunked 131k sweep (the production
 pattern: ~3 s of device work per rep), not a single-shot curve point.
 
 The full run and every standalone leg need a TPU and fail at start
@@ -782,8 +781,8 @@ def bench_secondary_models():
     """BASELINE configs #4 (kafka broker crash/restart sweep) and #2
     (etcd 3-node KV + lease with partition injection), checkers quiet.
 
-    The two legs INTERLEAVE their reps (rep-outer, model-inner — the
-    scripts/bench_packing.py A/B discipline) instead of running
+    The two legs INTERLEAVE their reps (rep-outer, model-inner, the
+    A/B discipline of docs/pallas_finding.md §0) instead of running
     back-to-back rep blocks, so a slow stretch of the machine cannot land
     on one model wholesale. Two more disciplines apply: the first
     post-warm interleaved pass is a DISCARDED warm-up rep (it still pays

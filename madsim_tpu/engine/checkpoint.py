@@ -830,20 +830,10 @@ def run_sweep_pipelined(
     return totals
 
 
-# EngineConfig fields that select equivalent-but-differently-laid-out
-# implementations (A/B instrumentation, historical knobs): schedules and
-# summaries are bit-identical across their values, so they must NOT
-# invalidate resumable checkpoints — toggling legacy_queue between runs
-# of one sweep directory resumes cleanly.
-_LAYOUT_ONLY_FIELDS = frozenset({"legacy_queue", "cond_interval"})
-
-
 def _sweep_fingerprint(workload: Workload, cfg: EngineConfig) -> str:
     """Identity of (model, model config, engine config) for the resumable
     sweep's stale-checkpoint guard. Model configs are NamedTuples of
-    plain values, so their repr is a stable fingerprint. Layout-only
-    engine fields (``_LAYOUT_ONLY_FIELDS``) are excluded: they cannot
-    change a chunk's summary, only its wall-clock. ``cover_bits`` is
+    plain values, so their repr is a stable fingerprint. ``cover_bits`` is
     INCLUDED: it changes the summary schema (``coverage_map`` appears),
     so chunk summaries written by a coverage-free workload must not
     silently merge into a coverage-guided sweep as zero coverage.
@@ -857,9 +847,7 @@ def _sweep_fingerprint(workload: Workload, cfg: EngineConfig) -> str:
     init = workload.init
     fn = getattr(init, "func", init)
     args = getattr(init, "args", ())
-    cfg_id = tuple(
-        v for f, v in zip(cfg._fields, cfg) if f not in _LAYOUT_ONLY_FIELDS
-    )
+    cfg_id = tuple(cfg)
     return (
         f"{fn.__module__}.{fn.__qualname__}|{args!r}|{cfg_id!r}"
         f"|cover{workload.cover_bits}|hist{hist_slots(workload)}"

@@ -136,20 +136,6 @@ class EngineConfig(NamedTuple):
     max_steps: int = 100_000
     jitter_lo_ns: int = 50
     jitter_hi_ns: int = 100
-    # A/B instrumentation (scripts/bench_packing.py): 1 = the pre-round-5
-    # queue layout with its redundant bool valid[Q] plane. Schedules are
-    # bit-identical either way; only the loop-carry footprint differs.
-    legacy_queue: int = 0
-    # HISTORICAL, kept for config compatibility (validated but unused):
-    # rounds 1-2 chunked the sweep as while(cond){fori(cond_interval){
-    # step}} assuming the termination check was the expensive part. TPU
-    # profiling (round 3) showed the opposite — the termination cond is
-    # free, while ANY nested device loop costs ~9x per step (measured
-    # 4.6 ms/step nested vs 0.43 ms/step flat at a 16k batch on v5e; the
-    # nesting forces the ~100 MB loop carry through HBM each inner trip
-    # instead of keeping it resident). The sweep is now a single flat
-    # while_loop with the cond evaluated every step.
-    cond_interval: int = 16
 
 
 class EngineState(NamedTuple):
@@ -188,13 +174,6 @@ def _init_one(
             f"queue_capacity ({cfg.queue_capacity}); every handler "
             "invocation must be able to enqueue its full emit batch"
         )
-    if cfg.cond_interval < 1:
-        raise ValueError(
-            f"cond_interval must be >= 1, got {cfg.cond_interval} (the "
-            "field is retained for config compatibility only — the sweep "
-            "loop now checks termination every step — but a value the old "
-            "chunked driver would have rejected is still a config bug)"
-        )
     key = seed_key(seed)
     # spec-as-data (engine/faults.py): a params-carrying workload builds
     # its fault schedule from this lane's traced FaultParams instead of a
@@ -202,10 +181,7 @@ def _init_one(
     wstate, emits = (
         workload.init(key) if params is None else workload.init(key, params)
     )
-    q = equeue.make(
-        cfg.queue_capacity, workload.payload_slots,
-        legacy=bool(cfg.legacy_queue),
-    )
+    q = equeue.make(cfg.queue_capacity, workload.payload_slots)
     q, overflow = equeue.push_many(q, emits.times, emits.kinds, emits.pays, emits.enables)
     return EngineState(
         seed=jnp.asarray(seed, jnp.int64),
@@ -374,12 +350,13 @@ def drive(workload: Workload, cfg: EngineConfig, state: EngineState):
     loop's trip count.
 
     ONE flat ``while_loop``, cond evaluated every step: nesting a second
-    device loop inside the body costs ~9x per step on TPU (the loop carry
-    round-trips HBM per inner iteration; see ``EngineConfig.cond_interval``
-    for the measurements), while the ``any(~done)`` reduction in the cond
-    is free. Exactly ``max_steps`` steps can run, keeping the sweep
-    bit-identical to ``run_traced``'s ``length=max_steps`` scan for
-    budget-cut seeds (finished seeds are frozen no-ops either way).
+    device loop inside the body costs ~9x per step on TPU (4.57 vs 0.43
+    ms/step at a 16k batch on v5e, docs/pallas_finding.md §1: the loop
+    carry round-trips HBM per inner iteration), while the ``any(~done)``
+    reduction in the cond is free. Exactly ``max_steps`` steps can run,
+    keeping the sweep bit-identical to ``run_traced``'s
+    ``length=max_steps`` scan for budget-cut seeds (finished seeds are
+    frozen no-ops either way).
     """
 
     def cond(carry):

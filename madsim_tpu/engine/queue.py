@@ -25,12 +25,8 @@ built for it, and is exact (see ``_free_count``).
 
 Occupancy is encoded in the time plane itself: a slot is free iff its time
 is ``INVALID_TIME`` (every constructor and removal maintains this), so no
-separate validity plane travels in the loop carry. The pre-round-5 layout
-kept an explicit ``bool valid[Q]`` plane; it survives as
-``LegacyEventQueue`` behind ``EngineConfig(legacy_queue=1)`` purely so the
-two layouts can be A/B-measured interleaved in one process
-(scripts/bench_packing.py, docs/pallas_finding.md §5) — both produce
-bit-identical schedules by construction.
+separate validity plane travels in the loop carry (a layout with a
+``bool valid[Q]`` plane measured 4.1% slower, docs/pallas_finding.md §5).
 
 Equal-time pops break ties *randomly* via a caller-supplied counter-RNG
 draw (``tie_u32``), mirroring the reference's uniformly-random ready-queue
@@ -43,7 +39,7 @@ surfaces it per seed so the run can be retried with a larger Q.
 
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple, Union
+from typing import NamedTuple, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -61,72 +57,16 @@ class EventQueue(NamedTuple):
     pay: jnp.ndarray  # int32[Q, P]
 
 
-class LegacyEventQueue(NamedTuple):
-    """Round-1..4 layout with the redundant validity plane (A/B only)."""
-
-    time: jnp.ndarray  # int64[Q]
-    kind: jnp.ndarray  # int32[Q]
-    pay: jnp.ndarray  # int32[Q, P]
-    valid: jnp.ndarray  # bool[Q]
-
-
-AnyQueue = Union[EventQueue, LegacyEventQueue]
-
-
-def make(capacity: int, payload_slots: int, legacy: bool = False) -> AnyQueue:
-    time = jnp.full((capacity,), INVALID_TIME, jnp.int64)
-    kind = jnp.zeros((capacity,), jnp.int32)
-    pay = jnp.zeros((capacity, payload_slots), jnp.int32)
-    if legacy:
-        return LegacyEventQueue(time, kind, pay, jnp.zeros((capacity,), bool))
-    return EventQueue(time, kind, pay)
-
-
-def _free(q: AnyQueue) -> jnp.ndarray:
-    """Free-slot mask; trace-time dispatch on the layout (zero runtime
-    cost — both encode the same fact, by the INVALID_TIME invariant)."""
-    if isinstance(q, LegacyEventQueue):
-        return ~q.valid
-    return q.time == INVALID_TIME
-
-
-def _rebuild(q: AnyQueue, time, kind, pay, occupy=None, vacate=None) -> AnyQueue:
-    """New queue with the same layout; legacy also updates its valid plane
-    (``occupy``/``vacate`` are slot masks)."""
-    if isinstance(q, LegacyEventQueue):
-        valid = q.valid
-        if occupy is not None:
-            valid = valid | occupy
-        if vacate is not None:
-            valid = valid & ~vacate
-        return LegacyEventQueue(time, kind, pay, valid)
-    return EventQueue(time, kind, pay)
-
-
-def push(
-    q: AnyQueue,
-    time: jnp.ndarray,
-    kind: jnp.ndarray,
-    pay: jnp.ndarray,
-    enable: jnp.ndarray,
-) -> Tuple[AnyQueue, jnp.ndarray]:
-    """Insert one event at the first free slot (no-op when ``enable`` is
-    False). Returns ``(queue', overflowed)``."""
-    free = _free(q)
-    have_room = jnp.any(free)
-    do = jnp.asarray(enable, bool) & have_room
-    mask = onehot(jnp.argmax(free), q.time.shape[0]) & do
-    overflow = enable & ~have_room
-    return (
-        _rebuild(
-            q,
-            jnp.where(mask, jnp.asarray(time, jnp.int64), q.time),
-            jnp.where(mask, jnp.asarray(kind, jnp.int32), q.kind),
-            jnp.where(mask[:, None], pay, q.pay),
-            occupy=mask,
-        ),
-        overflow,
+def make(capacity: int, payload_slots: int) -> EventQueue:
+    return EventQueue(
+        jnp.full((capacity,), INVALID_TIME, jnp.int64),
+        jnp.zeros((capacity,), jnp.int32),
+        jnp.zeros((capacity, payload_slots), jnp.int32),
     )
+
+
+def _free(q: EventQueue) -> jnp.ndarray:
+    return q.time == INVALID_TIME
 
 
 def _free_count(free: jnp.ndarray) -> jnp.ndarray:
@@ -146,12 +86,12 @@ def _free_count(free: jnp.ndarray) -> jnp.ndarray:
 
 
 def push_many(
-    q: AnyQueue,
+    q: EventQueue,
     times: jnp.ndarray,  # int64[E]
     kinds: jnp.ndarray,  # int32[E]
     pays: jnp.ndarray,  # int32[E, P]
     enables: jnp.ndarray,  # bool[E]
-) -> Tuple[AnyQueue, jnp.ndarray]:
+) -> Tuple[EventQueue, jnp.ndarray]:
     """Insert up to E events in ONE dense pass: emit ``e`` maps to the
     e-th free slot (ascending index — the same assignment a sequential
     first-free scan would make), whether or not earlier emits are enabled.
@@ -175,20 +115,18 @@ def push_many(
     count = _free_count(free)
     er = jnp.where(free, count - 1, E)
     time, kind, pay = q.time, q.kind, q.pay
-    write = jnp.zeros_like(free)
     for e in range(E):
         m = (er == e) & enables[e]
         time = jnp.where(m, times[e], time)
         kind = jnp.where(m, kinds[e], kind)
         pay = jnp.where(m[:, None], pays[e], pay)
-        write = write | m
     overflow = jnp.any(enables & (jnp.arange(E) >= count[-1]))
-    return _rebuild(q, time, kind, pay, occupy=write), overflow
+    return EventQueue(time, kind, pay), overflow
 
 
 def pop_min(
-    q: AnyQueue, enable=True, tie_u32=0
-) -> Tuple[AnyQueue, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    q: EventQueue, enable=True, tie_u32=0
+) -> Tuple[EventQueue, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Remove and return the earliest event; equal-time ties break
     uniformly-at-random by ``tie_u32`` (a counter-RNG draw — deterministic
     per seed+event, different across seeds: the reference's random ready-
@@ -224,13 +162,7 @@ def pop_min(
     kind = jnp.sum(jnp.where(mask & found, q.kind, 0), dtype=jnp.int32)
     pay = jnp.sum(jnp.where(mask[:, None], q.pay, 0), axis=0, dtype=jnp.int32)
     return (
-        _rebuild(
-            q,
-            jnp.where(rm, INVALID_TIME, q.time),
-            q.kind,
-            q.pay,
-            vacate=rm,
-        ),
+        EventQueue(jnp.where(rm, INVALID_TIME, q.time), q.kind, q.pay),
         t,
         kind,
         pay,
@@ -238,5 +170,5 @@ def pop_min(
     )
 
 
-def size(q: AnyQueue) -> jnp.ndarray:
+def size(q: EventQueue) -> jnp.ndarray:
     return jnp.sum((~_free(q)).astype(jnp.int32))

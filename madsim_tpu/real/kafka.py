@@ -18,9 +18,8 @@ wall-clock produce timestamps and poll deadlines::
     p = await config.create(kafka.FutureProducer)           # client side
 
 The pre-wire private framed codec stays A/B-able behind
-``MADSIM_KAFKA_LEGACY=1`` (both sides switch together, like the engine's
-``legacy_queue`` layout flag): useful for bisecting a wire-layer bug
-against the old transport, never the default.
+``MADSIM_KAFKA_LEGACY=1`` (both sides switch together): useful for
+bisecting a wire-layer bug against the old transport, never the default.
 """
 
 from __future__ import annotations

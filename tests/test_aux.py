@@ -171,16 +171,6 @@ def test_checkpoint_version_mismatch_raises(tmp_path):
         checkpoint.load_sweep(path, state)
 
 
-def test_cond_interval_validated():
-    import pytest
-
-    cfg = raft.RaftConfig(num_nodes=3)
-    ecfg = raft.engine_config(cfg)._replace(cond_interval=0)
-    wl = raft.workload(cfg)
-    with pytest.raises(ValueError, match="cond_interval"):
-        ecore.init_sweep(wl, ecfg, jnp.arange(2, dtype=jnp.int64))
-
-
 def test_resumable_chunked_sweep(tmp_path, monkeypatch):
     """Interrupted pod-scale sweeps resume at chunk granularity: completed
     chunks load from their summary files (zero device work), totals match
@@ -267,19 +257,30 @@ def test_resumable_chunked_sweep(tmp_path, monkeypatch):
     )
 
 
-def test_resumable_sweep_survives_layout_only_config_changes(
-    tmp_path, monkeypatch
-):
-    """legacy_queue (and cond_interval) select equivalent layouts whose
-    schedules are bit-identical (test_engine.py::
-    test_legacy_queue_layout_bit_identical), so a checkpoint directory
-    written under one layout must resume — all chunks from disk, zero
-    device work — under the other."""
+_RAFT3_FINGERPRINT = (
+    "madsim_tpu.models.raft._init|(RaftConfig(num_nodes=3, "
+    "election_lo_ns=150000000, election_hi_ns=300000000, "
+    "heartbeat_ns=50000000, commands=8, cmd_window_ns=4000000000, "
+    "cmd_retry_ns=50000000, cmd_max_retries=64, log_cap=32, crashes=1, "
+    "crash_window_ns=5000000000, restart_lo_ns=100000000, "
+    "restart_hi_ns=1000000000, loss_q32=42949673, lat_lo_ns=1000000, "
+    "lat_hi_ns=10000000, buggify_q32=0, history=16, volatile_state=False, "
+    "hist_slots=0, faults=None, event_mix=False),)"
+    "|(48, 500000000, 4000, 50, 100)|cover137|hist0|emix0"
+)
+
+
+def test_checkpoint_fingerprint_is_stable(tmp_path, monkeypatch):
+    """The resumable sweep's fingerprint is pinned to a literal, so a
+    checkpoint directory written by an earlier build of the same config
+    still resumes — all chunks from disk, zero device work — while a
+    semantic config change is refused."""
     import madsim_tpu.engine.core as ecore_mod
 
     cfg = raft.RaftConfig(num_nodes=3, crashes=1)
     ecfg = raft.engine_config(cfg, time_limit_ns=500_000_000, max_steps=4_000)
     wl = raft.workload(cfg)
+    assert checkpoint._sweep_fingerprint(wl, ecfg) == _RAFT3_FINGERPRINT
     seeds = jnp.arange(8, dtype=jnp.int64)
     d = str(tmp_path / "ckpts")
 
@@ -288,17 +289,13 @@ def test_resumable_sweep_survives_layout_only_config_changes(
     )
 
     def boom(*a, **k):
-        raise AssertionError("layout-only change must not re-run the sweep")
+        raise AssertionError("an unchanged config must not re-run the sweep")
 
     monkeypatch.setattr(ecore_mod, "run_sweep", boom)
-    for other in (
-        ecfg._replace(legacy_queue=1),
-        ecfg._replace(cond_interval=32),
-    ):
-        resumed = checkpoint.run_sweep_chunked_resumable(
-            wl, other, seeds, raft.sweep_summary, d, chunk_size=8
-        )
-        assert resumed == totals
+    resumed = checkpoint.run_sweep_chunked_resumable(
+        wl, ecfg, seeds, raft.sweep_summary, d, chunk_size=8
+    )
+    assert resumed == totals
     monkeypatch.undo()
 
     # a SEMANTIC config change must still be refused

@@ -22,16 +22,21 @@ from madsim_tpu.models import raft
 # -- queue -----------------------------------------------------------------
 
 
+def _push_one(q, time, kind, pay, enable=True):
+    """``push_many`` with a single emit."""
+    return equeue.push_many(
+        q,
+        jnp.array([time], jnp.int64),
+        jnp.array([kind], jnp.int32),
+        jnp.array([pay], jnp.int32),
+        jnp.array([enable]),
+    )
+
+
 def test_queue_push_pop_min_order():
     q = equeue.make(8, 2)
     for t in [50, 10, 30]:
-        q, ov = equeue.push(
-            q,
-            jnp.int64(t),
-            jnp.int32(t),
-            jnp.array([t, 0], jnp.int32),
-            jnp.asarray(True),
-        )
+        q, ov = _push_one(q, t, t, [t, 0])
         assert not bool(ov)
     times = []
     for _ in range(4):
@@ -46,17 +51,13 @@ def test_queue_push_pop_min_order():
 def test_queue_overflow_flag():
     q = equeue.make(2, 1)
     for i in range(3):
-        q, ov = equeue.push(
-            q, jnp.int64(i), jnp.int32(i), jnp.array([i], jnp.int32), jnp.asarray(True)
-        )
+        q, ov = _push_one(q, i, i, [i])
     assert bool(ov)
 
 
 def test_queue_disabled_push_is_noop():
     q = equeue.make(2, 1)
-    q, ov = equeue.push(
-        q, jnp.int64(1), jnp.int32(1), jnp.array([1], jnp.int32), jnp.asarray(False)
-    )
+    q, ov = _push_one(q, 1, 1, [1], enable=False)
     assert not bool(ov)
     assert int(equeue.size(q)) == 0
 
@@ -82,21 +83,22 @@ def _first_free_push(time, kind, pay, times, kinds, pays, enables):
 _PUSH_CASES = [(c, e) for c in (7, 60, 64, 129, 256) for e in ("wide", 1, 7, 15, "off")]
 
 
-@pytest.mark.parametrize("legacy", [False, True], ids=["packed", "legacy"])
+@pytest.mark.parametrize("slots", [3, 8], ids=["P3", "P8"])
 @pytest.mark.parametrize(
     "capacity,emits",
     _PUSH_CASES,
     ids=[str(c) if e == "wide" else f"{c}-e{e}" for c, e in _PUSH_CASES],
 )
-def test_push_many_matches_first_free_loop(capacity, emits, legacy):
+def test_push_many_matches_first_free_loop(capacity, emits, slots):
     """``push_many`` under vmap against the plain first-free loop: same
     planes, same overflow flag, on random free masks (lane 0 full, lane 1
     empty) and random enables (lane 2 all on). E is one emit, raft's step
     (7) and init (15) widths, or "wide", ``capacity // 4 + 3``, reaching
-    past the free count; "off" is the wide E with every emit disabled."""
+    past the free count; "off" is the wide E with every emit disabled.
+    P is 3 or raft's 8 payload slots."""
     n_emit = emits if isinstance(emits, int) else capacity // 4 + 3
-    lanes, slots = 64, 3
-    rng = np.random.default_rng(capacity * 100 + n_emit)
+    lanes = 64
+    rng = np.random.default_rng((capacity, n_emit, slots))
     occupied = rng.random((lanes, capacity)) < rng.random((lanes, 1))
     occupied[0], occupied[1] = True, False
     time = np.where(occupied, rng.integers(0, 1 << 40, (lanes, capacity)), equeue.INVALID_TIME)
@@ -111,8 +113,6 @@ def test_push_many_matches_first_free_loop(capacity, emits, legacy):
         enables[:] = False
 
     q = equeue.EventQueue(jnp.asarray(time), jnp.asarray(kind), jnp.asarray(pay))
-    if legacy:
-        q = equeue.LegacyEventQueue(*q, jnp.asarray(occupied))
     got, overflow = jax.jit(jax.vmap(equeue.push_many))(
         q, jnp.asarray(times), jnp.asarray(kinds), jnp.asarray(pays), jnp.asarray(enables)
     )
@@ -123,8 +123,6 @@ def test_push_many_matches_first_free_loop(capacity, emits, legacy):
         assert got_kind[i].tolist() == want[1], i
         assert got_pay[i].tolist() == want[2], i
         assert bool(overflow[i]) == want[3], i
-        if legacy:
-            assert (np.asarray(got.valid[i]) == (got_time[i] != equeue.INVALID_TIME)).all(), i
     # the cases at both ends were drawn: a full lane, an empty one
     assert overflow[0] == (emits != "off")
     assert overflow[1] == enables[1, capacity:].any()
@@ -178,6 +176,76 @@ def test_push_many_picks_values_without_slot_by_emit_plane():
         if e.primitive.name == "reduce_sum" and e.invars[0].aval.dtype == jnp.int64
     ]
     assert not sums, sums
+
+
+def _murmur_prio(slot, tie):
+    """``pop_min``'s per-slot tie-break priority in plain Python ints."""
+    m = 0xFFFFFFFF
+    x = ((slot * 2654435761) & m) ^ tie
+    x ^= x >> 16
+    x = (x * 0x85EBCA6B) & m
+    x ^= x >> 13
+    x = (x * 0xC2B2AE35) & m
+    return x ^ (x >> 16)
+
+
+def _pop_min_loop(time, kind, pay, tie, enable):
+    """One lane of ``pop_min`` as a plain loop: the minimum time, the
+    candidate holding it with the least murmur priority, and the slot
+    vacated only if found and enabled. An empty queue reports the
+    sentinel time and kind 0, and the payload row of the slot the
+    tie-break picks among all slots."""
+    time = [int(t) for t in time]
+    t = min(time)
+    found = t != equeue.INVALID_TIME
+    slot = min((i for i, x in enumerate(time) if x == t), key=lambda i: _murmur_prio(i, tie))
+    if found and enable:
+        time[slot] = equeue.INVALID_TIME
+    return time, t, int(kind[slot]) if found else 0, [int(p) for p in pay[slot]], found
+
+
+_POP_PATTERNS = ("random", "ties", "empty")
+
+
+@pytest.mark.parametrize("pattern", _POP_PATTERNS)
+@pytest.mark.parametrize("capacity", [7, 60, 64, 129, 256])
+def test_pop_min_matches_plain_reference(capacity, pattern):
+    """``pop_min`` under vmap against the plain loop, bit for bit: the
+    popped time, kind, payload and ``found``, and the queue afterwards —
+    the slot vacated only where ``enable``, every plane untouched where
+    not. "random" draws times from a wide range, "ties" from two values
+    so most lanes hold several slots at the minimum, "empty" holds none.
+    Lane 0 is full, lane 1 holds one event, and the tie draws include
+    0 and 0xFFFFFFFF."""
+    lanes, slots = 64, 8
+    rng = np.random.default_rng((capacity, _POP_PATTERNS.index(pattern)))
+    occupied = rng.random((lanes, capacity)) < rng.random((lanes, 1))
+    occupied[0], occupied[1] = True, np.arange(capacity) == capacity // 2
+    if pattern == "empty":
+        occupied[:] = False
+    values = rng.integers(0, 1 << 40, (lanes, capacity))
+    if pattern == "ties":
+        values = rng.choice(np.array([1 << 33, (1 << 33) + 1]), (lanes, capacity))
+    time = np.where(occupied, values, equeue.INVALID_TIME)
+    kind = rng.integers(-99, 99, (lanes, capacity)).astype(np.int32)
+    pay = rng.integers(-99, 99, (lanes, capacity, slots)).astype(np.int32)
+    tie = rng.integers(0, 1 << 32, lanes, dtype=np.uint32)
+    tie[:4] = [0, 0xFFFFFFFF, 0, 0xFFFFFFFF]
+    enable = rng.random(lanes) < 0.7
+    enable[:2] = True
+
+    q = equeue.EventQueue(jnp.asarray(time), jnp.asarray(kind), jnp.asarray(pay))
+    got = jax.jit(jax.vmap(equeue.pop_min))(q, jnp.asarray(enable), jnp.asarray(tie))
+    got_q, got_t, got_kind, got_pay, got_found = jax.tree.map(np.asarray, got)
+    assert (got_q.kind == kind).all() and (got_q.pay == pay).all()
+    for i in range(lanes):
+        want = _pop_min_loop(time[i], kind[i], pay[i], int(tie[i]), bool(enable[i]))
+        assert got_q.time[i].tolist() == want[0], i
+        assert int(got_t[i]) == want[1], i
+        assert int(got_kind[i]) == want[2], i
+        assert got_pay[i].tolist() == want[3], i
+        assert bool(got_found[i]) == want[4], i
+    assert got_found[:2].all() == (pattern != "empty")
 
 
 # -- rng -------------------------------------------------------------------
@@ -417,10 +485,7 @@ def test_pop_tie_break_varies_with_draw():
     def fill():
         q = equeue.make(8, 1)
         for k in range(4):
-            q, _ = equeue.push(
-                q, jnp.int64(100), jnp.int32(k),
-                jnp.array([k], jnp.int32), jnp.asarray(True),
-            )
+            q, _ = _push_one(q, 100, k, [k])
         return q
 
     def pop_order(tie_seq):
@@ -444,10 +509,7 @@ def test_pop_tie_break_prefers_earlier_time():
     """The tie-break only applies within the minimum time bucket."""
     q = equeue.make(4, 1)
     for t, k in [(200, 0), (100, 1), (200, 2)]:
-        q, _ = equeue.push(
-            q, jnp.int64(t), jnp.int32(k), jnp.array([k], jnp.int32),
-            jnp.asarray(True),
-        )
+        q, _ = _push_one(q, t, k, [k])
     for u in (0, 1, 0xFFFFFFFF, 0x13572468):
         _, t, kind, _, found = equeue.pop_min(q, tie_u32=jnp.uint32(u))
         assert bool(found) and int(t) == 100 and int(kind) == 1
@@ -480,8 +542,7 @@ def test_same_timestamp_events_interleave_across_seeds():
         )
 
     wl = Workload(init=init, handle=handle, num_rand=1, payload_slots=1, max_emits=1)
-    cfg = EngineConfig(queue_capacity=4, time_limit_ns=10_000, max_steps=8,
-                       cond_interval=1)
+    cfg = EngineConfig(queue_capacity=4, time_limit_ns=10_000, max_steps=8)
     final = ecore.run_sweep(wl, cfg, jnp.arange(64, dtype=jnp.int64))
     first = np.asarray(final.wstate)[:, 0]
     assert set(first.tolist()) == {1, 2}, (
@@ -525,7 +586,7 @@ def test_queue_fills_to_exact_capacity_without_overflow():
     cap = 8
     wl = _spawner_workload()
     cfg = EngineConfig(queue_capacity=cap, time_limit_ns=1 << 40,
-                       max_steps=cap - 1, cond_interval=1)
+                       max_steps=cap - 1)
     final = ecore.run_sweep(wl, cfg, jnp.arange(4, dtype=jnp.int64))
     assert (np.asarray(final.qmax) == cap).all()
     assert not np.asarray(final.overflow).any()
@@ -537,7 +598,7 @@ def test_queue_overflow_latches_exactly_past_capacity():
     cap = 8
     wl = _spawner_workload()
     cfg = EngineConfig(queue_capacity=cap, time_limit_ns=1 << 40,
-                       max_steps=cap, cond_interval=1)
+                       max_steps=cap)
     final = ecore.run_sweep(wl, cfg, jnp.arange(4, dtype=jnp.int64))
     assert (np.asarray(final.qmax) == cap).all()  # never exceeds capacity
     assert np.asarray(final.overflow).all()
@@ -578,28 +639,6 @@ def test_chunked_sweep_matches_unchunked_with_ragged_tail():
         if jnp.issubdtype(a.dtype, jax.dtypes.prng_key):
             a, b = jax.random.key_data(a), jax.random.key_data(b)
         assert jnp.array_equal(jax.device_get(a), jax.device_get(b))
-
-
-def test_legacy_queue_layout_bit_identical():
-    """The pre-round-5 queue layout (explicit valid plane,
-    EngineConfig(legacy_queue=1)) and the packed layout (occupancy encoded
-    in the time plane) must produce bit-identical schedules — the A/B in
-    scripts/bench_packing.py measures a pure layout effect, nothing else."""
-    cfg = raft.RaftConfig(num_nodes=3, crashes=1)
-    ecfg = raft.engine_config(cfg, time_limit_ns=500_000_000, max_steps=4_000)
-    legacy_ecfg = ecfg._replace(legacy_queue=1)
-    wl = raft.workload(cfg)
-    seeds = jnp.arange(16, dtype=jnp.int64)
-    packed = ecore.run_sweep(wl, ecfg, seeds)
-    legacy = ecore.run_sweep(wl, legacy_ecfg, seeds)
-    assert jnp.array_equal(packed.ctr, legacy.ctr)
-    assert jnp.array_equal(packed.now_ns, legacy.now_ns)
-    assert jnp.array_equal(packed.queue.time, legacy.queue.time)
-    for a, b in zip(jax.tree.leaves(packed.wstate), jax.tree.leaves(legacy.wstate)):
-        assert jnp.array_equal(jax.device_get(a), jax.device_get(b))
-    # the legacy layout really does carry the extra plane
-    assert hasattr(legacy.queue, "valid") and not hasattr(packed.queue, "valid")
-    assert raft.sweep_summary(packed) == raft.sweep_summary(legacy)
 
 
 def test_buggify_latency_spikes_amplify_and_stay_deterministic():

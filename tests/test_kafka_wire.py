@@ -326,8 +326,8 @@ def test_wire_replay_of_recorded_transcript_is_byte_identical():
 
 def test_real_mode_legacy_codec_flag_roundtrip(monkeypatch):
     """MADSIM_KAFKA_LEGACY=1 swaps BOTH sides back to the pre-wire
-    private framed codec (the A/B escape hatch, like the engine's
-    legacy_queue); the client API is oblivious."""
+    private framed codec (the A/B escape hatch); the client API is
+    oblivious."""
     monkeypatch.setenv("MADSIM_KAFKA_LEGACY", "1")
     from madsim_tpu import real
     from madsim_tpu.kafka import NewTopic

@@ -3,9 +3,9 @@
 Nothing runs: each program is lowered against a *described* v5e chip
 (``jax.experimental.topologies``) and compiled by the TPU compiler that
 ships with libtpu, at the sizes ``chip_smoke.py`` runs them. That catches
-what interpret mode and the CPU backend cannot — a Pallas block the
-Mosaic tiling refuses, a program that overflows device memory — for no
-chip time. A compile that passes is not a chip run.
+what the CPU backend cannot — a program the TPU compiler refuses, or one
+that overflows device memory — for no chip time. A compile that passes is
+not a chip run.
 
 The topology is described only inside the ``topo`` fixture (never at
 import, in a ``skipif`` or in ``parametrize``): the first process that
@@ -24,13 +24,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from madsim_tpu.engine import core, megakernel, pallas_queue
-from madsim_tpu.models import etcd, raft
+from madsim_tpu.engine import core
+from madsim_tpu.models import etcd, kafka, raft, s3
 from madsim_tpu.oracle import screen
 
 RAFT_LANES = 16_384  # core.pick_chunk_size for the 5-node raft config
 ETCD_LANES = 8_192  # core.pick_chunk_size for etcd at hist_slots=256
-MEGA_TILE = 256
 
 
 @pytest.fixture(scope="module")
@@ -75,13 +74,23 @@ def _compile(fn, *args):
     return compiled
 
 
-def test_raft_sweep_compiles_for_v5e(one_chip):
-    cfg = raft.RaftConfig(num_nodes=5, crashes=1)
-    ecfg = raft.engine_config(cfg, time_limit_ns=3_000_000_000)
-    wl = raft.workload(cfg)
-    seeds = jax.ShapeDtypeStruct((RAFT_LANES,), jnp.int64, sharding=one_chip)
+@pytest.mark.parametrize("model", ["raft", "etcd", "kafka", "s3"])
+def test_sweep_compiles_for_v5e(one_chip, model):
+    """``init_sweep`` and ``drive`` for each device model: raft as the
+    benchmark runs it (5 nodes, 3 virtual s), the others at their default
+    configs and ``core.pick_chunk_size`` lanes."""
+    if model == "raft":
+        cfg = raft.RaftConfig(num_nodes=5, crashes=1)
+        wl = raft.workload(cfg)
+        ecfg = raft.engine_config(cfg, time_limit_ns=3_000_000_000)
+        lanes = RAFT_LANES
+    else:
+        mod = {"etcd": etcd, "kafka": kafka, "s3": s3}[model]
+        wl, ecfg = mod.workload(), mod.engine_config()
+        lanes = core.pick_chunk_size(wl, ecfg)
+    seeds = jax.ShapeDtypeStruct((lanes,), jnp.int64, sharding=one_chip)
     _compile(partial(core.init_sweep, wl, ecfg), seeds)
-    state = _state_shapes(wl, ecfg, RAFT_LANES, one_chip)
+    state = _state_shapes(wl, ecfg, lanes, one_chip)
     mem = _compile(partial(core.drive, wl, ecfg), state).memory_analysis()
     assert mem.argument_size_in_bytes < 16 << 30
 
@@ -97,24 +106,3 @@ def test_etcd_screen_compiles_for_v5e(one_chip):
         return screen.screen_sweep(planes, spec)
 
     _compile(run, final.seed, final.hist_rec, final.hist_t, final.hist_len)
-
-
-def test_pallas_pop_min_compiles_for_v5e(one_chip):
-    cfg = raft.RaftConfig(num_nodes=5, crashes=1)
-    ecfg = raft.engine_config(cfg)
-    state = _state_shapes(raft.workload(cfg), ecfg, RAFT_LANES, one_chip)
-    tie = jax.ShapeDtypeStruct((RAFT_LANES,), jnp.uint32, sharding=one_chip)
-    compiled = _compile(pallas_queue.pop_min_pallas, state.queue, tie)
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_megakernel_compiles_for_v5e(one_chip):
-    wl = megakernel.probe_workload()
-    cfg = megakernel.probe_config(max_steps=64)
-    state = _state_shapes(wl, cfg, 4 * MEGA_TILE, one_chip)
-    run = partial(
-        megakernel.run_megasweep, steps=64, time_limit=cfg.time_limit_ns,
-        tile=MEGA_TILE,
-    )
-    compiled = _compile(run, state)
-    assert "tpu_custom_call" in compiled.as_text()
