@@ -66,8 +66,45 @@ from .core import EngineConfig, EngineState, Workload
 #     CANNOT resume an event-mix-ENABLED sweep — the counters for the
 #     already-run steps were never recorded — and the reader rejects
 #     that combination instead of silently zero-filling.
-_FORMAT_VERSION = 10
-_READABLE_VERSIONS = (6, 7, 8, 9, 10)
+# v11: the event queue carries its payload as P ``int32[Q]`` planes, one
+#     per word (engine/queue.py), not one stacked ``int32[Q, P]`` leaf, so
+#     every leaf after the queue's ``kind`` moved up by P - 1. This reader
+#     still ACCEPTS v6-v10 files: it reads their stacked payload leaf in
+#     the old position and splits it into the planes (``_from_stored``).
+_FORMAT_VERSION = 11
+_READABLE_VERSIONS = (6, 7, 8, 9, 10, 11)
+_SPLIT_PAY_VERSION = 11  # first version with one leaf per payload plane
+
+
+def _pay_span(like: EngineState) -> Tuple[int, int]:
+    """(index of the queue's first payload plane among ``like``'s leaves,
+    number of planes P)."""
+    paths = [path for path, _ in jax.tree_util.tree_flatten_with_path(like)[0]]
+    first = paths.index((
+        jax.tree_util.GetAttrKey("queue"),
+        jax.tree_util.GetAttrKey("pay"),
+        jax.tree_util.SequenceKey(0),
+    ))
+    return first, len(like.queue.pay)
+
+
+def _stored(leaves: list, found: int, first: int, planes: int) -> list:
+    """``like``'s leaves in the order a v``found`` file stores them: before
+    v11 the P payload planes were one ``[..., Q, P]`` leaf."""
+    if found >= _SPLIT_PAY_VERSION:
+        return leaves
+    plane = leaves[first]
+    stacked = jax.ShapeDtypeStruct((*plane.shape, planes), plane.dtype)
+    return leaves[:first] + [stacked] + leaves[first + planes:]
+
+
+def _from_stored(leaves: list, found: int, first: int, planes: int) -> list:
+    """Leaves read from a v``found`` file in the current order: a
+    pre-v11 stacked payload leaf split into its P planes."""
+    if found >= _SPLIT_PAY_VERSION:
+        return leaves
+    pay = leaves[first]
+    return leaves[:first] + [pay[..., p] for p in range(planes)] + leaves[first + 1:]
 
 
 def _restore_leaf(data, i: int, leaf, path: str):
@@ -161,10 +198,12 @@ def load_sweep(path: str, like: EngineState) -> EngineState:
             "produce a fresh checkpoint)"
         )
     leaves, treedef = jax.tree.flatten(like)
+    first, planes = _pay_span(like)
     out = [
-        _restore_leaf(data, i, leaf, path) for i, leaf in enumerate(leaves)
+        _restore_leaf(data, i, leaf, path)
+        for i, leaf in enumerate(_stored(leaves, found, first, planes))
     ]
-    return jax.tree.unflatten(treedef, out)
+    return jax.tree.unflatten(treedef, _from_stored(out, found, first, planes))
 
 
 def save_stream(
@@ -231,10 +270,12 @@ def load_stream(path: str, like: EngineState):
             "(engine/stream.stream_sweep ckpt_path=)"
         )
     leaves, treedef = jax.tree.flatten(like)
+    first, planes = _pay_span(like)
     out = [
-        _restore_leaf(data, i, leaf, path) for i, leaf in enumerate(leaves)
+        _restore_leaf(data, i, leaf, path)
+        for i, leaf in enumerate(_stored(leaves, found, first, planes))
     ]
-    state = jax.tree.unflatten(treedef, out)
+    state = jax.tree.unflatten(treedef, _from_stored(out, found, first, planes))
     meta = json.loads(bytes(bytearray(data["__stream__"])).decode())
     pending = {}
     susp = {}
@@ -243,14 +284,15 @@ def load_stream(path: str, like: EngineState):
         # evmix leaf; a width-0 plane row is an empty array of the
         # like-leaf's per-lane shape (the only legal missing case —
         # _restore_leaf already rejected non-empty gaps above)
-        pending[int(it)] = [
+        row = [
             (
                 data[f"pend_{j}"][idx]
                 if f"pend_{j}" in data
                 else np.zeros(out[j].shape[1:], np.asarray(out[j]).dtype)
             )
-            for j in range(len(leaves))
+            for j in range(len(out))
         ]
+        pending[int(it)] = _from_stored(row, found, first, planes)
         bit = meta["susp"][idx]
         if bit is not None:
             susp[int(it)] = bool(bit)

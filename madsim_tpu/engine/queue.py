@@ -6,9 +6,9 @@ pointer chasing and data-dependent shapes defeat XLA. The device engine uses
 the classic SoA alternative (SURVEY.md §7 "hard parts" #2): a fixed-capacity
 slot table per seed —
 
-    time  : int64[Q]   absolute deadline, ns (INVALID_TIME when free)
-    kind  : int32[Q]   event discriminant (workload-defined)
-    pay   : int32[Q,P] payload slots
+    time  : int64[Q]        absolute deadline, ns (INVALID_TIME when free)
+    kind  : int32[Q]        event discriminant (workload-defined)
+    pay   : P x int32[Q]    payload slots, one plane per payload word
 
 ``pop_min`` = min + one-hot invalidate; ``push_many`` = one masked select
 per emit, each slot taking the emit whose index is its rank among free
@@ -22,6 +22,17 @@ as a matmul with a constant upper-triangular 0/1 matrix. ``jnp.cumsum``
 lowers on TPU to a whole-axis ``reduce_window`` that XLA expands into an
 O(Q²) sum on the VPU; the matmul does the same O(Q²) work on the unit
 built for it, and is exact (see ``_free_count``).
+
+The payload is P separate ``[Q]`` planes, not one ``int32[Q, P]`` array,
+for the TPU's tiling. Batched over lanes, a ``[lanes, Q]`` plane is tiled
+``T(8,128)`` with lanes on the 128 vector lanes and Q on the 8 sublanes,
+and so are the ``[lanes, Q]`` masks that push's writes and pop's read
+select with: mask and plane line up tile for tile. A stacked
+``[lanes, Q, P]`` array is tiled with P on the sublanes, so every masked
+write would pull row q out of a packed mask tile and broadcast it across
+the P sublanes, for every tile and every emit, and a P below 8 would pad
+to 8. The drive loop's carry sits in on-chip VMEM, so these passes are
+bound by vector work per tile, not by memory bandwidth.
 
 Occupancy is encoded in the time plane itself: a slot is free iff its time
 is ``INVALID_TIME`` (every constructor and removal maintains this), so no
@@ -54,14 +65,14 @@ _HASH_MULT = 2654435761  # Knuth multiplicative hash constant
 class EventQueue(NamedTuple):
     time: jnp.ndarray  # int64[Q]; INVALID_TIME == free slot
     kind: jnp.ndarray  # int32[Q]
-    pay: jnp.ndarray  # int32[Q, P]
+    pay: Tuple[jnp.ndarray, ...]  # P planes of int32[Q], one per payload word
 
 
 def make(capacity: int, payload_slots: int) -> EventQueue:
     return EventQueue(
         jnp.full((capacity,), INVALID_TIME, jnp.int64),
         jnp.zeros((capacity,), jnp.int32),
-        jnp.zeros((capacity, payload_slots), jnp.int32),
+        tuple(jnp.zeros((capacity,), jnp.int32) for _ in range(payload_slots)),
     )
 
 
@@ -105,23 +116,26 @@ def push_many(
     plane — straight into the queue planes: no ``[Q, E]`` one-hot and
     no sum over E. No sort, no top_k, no scatter.
 
-    Emit ``e``'s payload is the row ``pays[e]``. Slicing ``pays``
-    flattened instead makes push's slice cheaper on a v5e but changes
-    the layout in which the handler builds ``pays``, which costs the
-    handler more than push saves (raft, P = 8).
+    Emit ``e``'s payload is the row ``pays[e]``; word ``p`` of it goes
+    into plane ``pay[p]`` under the same mask ``m`` as the time and kind,
+    so every write lines up with its mask tile for tile (module
+    docstring). Slicing ``pays`` flattened instead makes push's slice
+    cheaper on a v5e but changes the layout in which the handler builds
+    ``pays``, which costs the handler more than push saves (raft, P = 8).
     """
     E = times.shape[0]
     free = _free(q)
     count = _free_count(free)
     er = jnp.where(free, count - 1, E)
-    time, kind, pay = q.time, q.kind, q.pay
+    time, kind, pay = q.time, q.kind, list(q.pay)
     for e in range(E):
         m = (er == e) & enables[e]
         time = jnp.where(m, times[e], time)
         kind = jnp.where(m, kinds[e], kind)
-        pay = jnp.where(m[:, None], pays[e], pay)
+        for p in range(len(pay)):
+            pay[p] = jnp.where(m, pays[e, p], pay[p])
     overflow = jnp.any(enables & (jnp.arange(E) >= count[-1]))
-    return EventQueue(time, kind, pay), overflow
+    return EventQueue(time, kind, tuple(pay)), overflow
 
 
 def pop_min(
@@ -132,7 +146,9 @@ def pop_min(
     per seed+event, different across seeds: the reference's random ready-
     queue pop semantics).
 
-    Returns ``(queue', time, kind, pay, found)``; when the queue is empty
+    Returns ``(queue', time, kind, pay, found)``, ``pay`` an ``int32[P]``
+    vector read with one masked sum over Q per payload plane, each under
+    the slot mask's own tiling (module docstring); when the queue is empty
     ``found`` is False and time is INVALID_TIME. With ``enable=False`` the
     queue is left untouched (lets a masked-out seed skip its pop without a
     whole-array select).
@@ -160,7 +176,10 @@ def pop_min(
     mask = onehot(slot, capacity)
     rm = mask & found & jnp.asarray(enable, bool)
     kind = jnp.sum(jnp.where(mask & found, q.kind, 0), dtype=jnp.int32)
-    pay = jnp.sum(jnp.where(mask[:, None], q.pay, 0), axis=0, dtype=jnp.int32)
+    pay = jnp.array(
+        [jnp.sum(jnp.where(mask, plane, 0), dtype=jnp.int32) for plane in q.pay],
+        jnp.int32,
+    )
     return (
         EventQueue(jnp.where(rm, INVALID_TIME, q.time), q.kind, q.pay),
         t,

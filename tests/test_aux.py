@@ -153,6 +153,88 @@ def test_sweep_checkpoint_resume_bit_exact(tmp_path):
     assert jnp.array_equal(resumed.wstate.violation, full.wstate.violation)
 
 
+def _rewrite_as_v10(src, dst, state):
+    """The snapshot at ``src`` as format v10 stored it, written to ``dst``:
+    the queue's P payload planes (``leaf_``/``pend_`` entries) stacked
+    into one ``[..., Q, P]`` entry at the first plane's index, and every
+    later entry numbered P - 1 lower."""
+    first, planes = checkpoint._pay_span(state)
+    data = dict(np.load(src))
+    out = {"__version__": np.asarray(10)}
+    for name, arr in data.items():
+        prefix = name[:5]
+        if prefix not in ("leaf_", "pend_"):
+            if name != "__version__":
+                out[name] = arr
+            continue
+        i, sep, suffix = name[5:].partition("__")
+        i = int(i)
+        if i == first:
+            out[name] = np.stack([data[f"{prefix}{first + p}"] for p in range(planes)], -1)
+        elif i > first:
+            if i >= first + planes:
+                out[f"{prefix}{i - planes + 1}{sep}{suffix}"] = arr
+        else:
+            out[name] = arr
+    np.savez_compressed(dst, **out)
+
+
+def test_v10_checkpoint_resumes_bit_exact(tmp_path):
+    """A v10 snapshot, whose queue payload is one stacked leaf, restores
+    every leaf bit for bit into the per-word planes and resumes to the
+    uninterrupted run's final state."""
+    import jax
+
+    cfg = raft.RaftConfig(num_nodes=3, crashes=1)
+    ecfg = raft.engine_config(cfg, queue_capacity=32,
+                              time_limit_ns=1_000_000_000, max_steps=8_000)
+    wl = raft.workload(cfg)
+    seeds = jnp.arange(8, dtype=jnp.int64)
+    state = ecore.init_sweep(wl, ecfg, seeds)
+    stepper = jax.jit(lambda s: ecore.step_batch(wl, ecfg, s))
+    for _ in range(100):
+        state = stepper(state)
+    v11, v10 = str(tmp_path / "v11.npz"), str(tmp_path / "v10.npz")
+    checkpoint.save_sweep(state, v11)
+    _rewrite_as_v10(v11, v10, state)
+    assert "leaf_1__key" in np.load(v10)
+    assert len(np.load(v10).files) == len(np.load(v11).files) - raft.PAYLOAD_SLOTS + 1
+
+    restored = checkpoint.load_sweep(v10, ecore.init_sweep(wl, ecfg, seeds))
+    assert jax.tree.structure(restored) == jax.tree.structure(state)
+    for a, b in zip(jax.tree.leaves(restored), jax.tree.leaves(state)):
+        if jnp.issubdtype(a.dtype, jax.dtypes.prng_key):
+            a, b = jax.random.key_data(a), jax.random.key_data(b)
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    resumed = checkpoint.resume_sweep(wl, ecfg, restored)
+    full = ecore.run_sweep(wl, ecfg, seeds)
+    for a, b in zip(jax.tree.leaves(resumed), jax.tree.leaves(full)):
+        if jnp.issubdtype(a.dtype, jax.dtypes.prng_key):
+            a, b = jax.random.key_data(a), jax.random.key_data(b)
+        assert np.array_equal(a, b)
+
+
+def test_v10_stream_snapshot_resumes_bit_exact(tmp_path):
+    """An interrupted stream's v10 snapshot (payload stacked in the pool
+    state and in the pending rows) resumes to the uninterrupted result."""
+    from madsim_tpu.engine.stream import stream_sweep
+
+    cfg = raft.RaftConfig(num_nodes=3)
+    ecfg = raft.engine_config(cfg, time_limit_ns=500_000_000, max_steps=4_000)
+    wl = raft.workload(cfg)
+    seeds = jnp.arange(24, dtype=jnp.int64)
+    kw = dict(chunk_size=8, pool_size=8, round_steps=64)
+    full = stream_sweep(wl, ecfg, seeds, raft.sweep_summary, **kw)
+    v11, v10 = str(tmp_path / "v11.npz"), str(tmp_path / "v10.npz")
+    stream_sweep(wl, ecfg, seeds, raft.sweep_summary, ckpt_path=v11,
+                 stop_after_rounds=2, **kw)
+    _rewrite_as_v10(v11, v10, ecore.init_sweep(wl, ecfg, seeds[:8]))
+    assert int(np.load(v10)["__version__"]) == 10
+    resumed = stream_sweep(wl, ecfg, seeds, raft.sweep_summary,
+                           resume_from=v10, **kw)
+    assert resumed == full
+
+
 def test_checkpoint_version_mismatch_raises(tmp_path):
     import numpy as np
     import pytest

@@ -80,6 +80,18 @@ def _first_free_push(time, kind, pay, times, kinds, pays, enables):
     return time, kind, pay, overflow
 
 
+def _planes(pay):
+    """A stacked ``[..., Q, P]`` payload as the queue carries it: one
+    ``[..., Q]`` plane per payload word."""
+    return tuple(jnp.asarray(pay[..., p]) for p in range(pay.shape[-1]))
+
+
+def _stacked(planes):
+    """The queue's payload planes stacked back to ``[..., Q, P]``."""
+    assert len({np.shape(p) for p in planes}) == 1
+    return np.stack([np.asarray(p) for p in planes], axis=-1)
+
+
 _PUSH_CASES = [(c, e) for c in (7, 60, 64, 129, 256) for e in ("wide", 1, 7, 15, "off")]
 
 
@@ -112,11 +124,13 @@ def test_push_many_matches_first_free_loop(capacity, emits, slots):
     if emits == "off":
         enables[:] = False
 
-    q = equeue.EventQueue(jnp.asarray(time), jnp.asarray(kind), jnp.asarray(pay))
+    q = equeue.EventQueue(jnp.asarray(time), jnp.asarray(kind), _planes(pay))
     got, overflow = jax.jit(jax.vmap(equeue.push_many))(
         q, jnp.asarray(times), jnp.asarray(kinds), jnp.asarray(pays), jnp.asarray(enables)
     )
-    got_time, got_kind, got_pay, overflow = map(np.asarray, (got.time, got.kind, got.pay, overflow))
+    assert len(got.pay) == slots and got.pay[0].shape == (lanes, capacity)
+    got_time, got_kind, overflow = map(np.asarray, (got.time, got.kind, overflow))
+    got_pay = _stacked(got.pay)
     for i in range(lanes):
         want = _first_free_push(time[i], kind[i], pay[i], times[i], kinds[i], pays[i], enables[i])
         assert got_time[i].tolist() == want[0], i
@@ -234,10 +248,11 @@ def test_pop_min_matches_plain_reference(capacity, pattern):
     enable = rng.random(lanes) < 0.7
     enable[:2] = True
 
-    q = equeue.EventQueue(jnp.asarray(time), jnp.asarray(kind), jnp.asarray(pay))
+    q = equeue.EventQueue(jnp.asarray(time), jnp.asarray(kind), _planes(pay))
     got = jax.jit(jax.vmap(equeue.pop_min))(q, jnp.asarray(enable), jnp.asarray(tie))
     got_q, got_t, got_kind, got_pay, got_found = jax.tree.map(np.asarray, got)
-    assert (got_q.kind == kind).all() and (got_q.pay == pay).all()
+    assert (got_q.kind == kind).all() and (_stacked(got_q.pay) == pay).all()
+    assert got_pay.shape == (lanes, slots)
     for i in range(lanes):
         want = _pop_min_loop(time[i], kind[i], pay[i], int(tie[i]), bool(enable[i]))
         assert got_q.time[i].tolist() == want[0], i

@@ -16,6 +16,7 @@ for a described chip cannot be read back without one.
 """
 
 import os
+import re
 from functools import partial
 from types import SimpleNamespace
 
@@ -74,25 +75,54 @@ def _compile(fn, *args):
     return compiled
 
 
-@pytest.mark.parametrize("model", ["raft", "etcd", "kafka", "s3"])
-def test_sweep_compiles_for_v5e(one_chip, model):
-    """``init_sweep`` and ``drive`` for each device model: raft as the
-    benchmark runs it (5 nodes, 3 virtual s), the others at their default
-    configs and ``core.pick_chunk_size`` lanes."""
+def _model(model):
+    """(workload, engine config, lanes): raft as the benchmark runs it
+    (5 nodes, 3 virtual s), the others at their default configs and
+    ``core.pick_chunk_size`` lanes."""
     if model == "raft":
         cfg = raft.RaftConfig(num_nodes=5, crashes=1)
         wl = raft.workload(cfg)
-        ecfg = raft.engine_config(cfg, time_limit_ns=3_000_000_000)
-        lanes = RAFT_LANES
-    else:
-        mod = {"etcd": etcd, "kafka": kafka, "s3": s3}[model]
-        wl, ecfg = mod.workload(), mod.engine_config()
-        lanes = core.pick_chunk_size(wl, ecfg)
+        return wl, raft.engine_config(cfg, time_limit_ns=3_000_000_000), RAFT_LANES
+    mod = {"etcd": etcd, "kafka": kafka, "s3": s3}[model]
+    wl, ecfg = mod.workload(), mod.engine_config()
+    return wl, ecfg, core.pick_chunk_size(wl, ecfg)
+
+
+@pytest.fixture(scope="module")
+def raft_drive(one_chip):
+    """raft's compiled ``drive``, shared by the tests that read it."""
+    wl, ecfg, lanes = _model("raft")
+    return _compile(partial(core.drive, wl, ecfg), _state_shapes(wl, ecfg, lanes, one_chip))
+
+
+@pytest.mark.parametrize("model", ["raft", "etcd", "kafka", "s3"])
+def test_sweep_compiles_for_v5e(one_chip, model, request):
+    """``init_sweep`` and ``drive`` for each device model."""
+    wl, ecfg, lanes = _model(model)
     seeds = jax.ShapeDtypeStruct((lanes,), jnp.int64, sharding=one_chip)
     _compile(partial(core.init_sweep, wl, ecfg), seeds)
-    state = _state_shapes(wl, ecfg, lanes, one_chip)
-    mem = _compile(partial(core.drive, wl, ecfg), state).memory_analysis()
-    assert mem.argument_size_in_bytes < 16 << 30
+    if model == "raft":
+        drive = request.getfixturevalue("raft_drive")
+    else:
+        drive = _compile(partial(core.drive, wl, ecfg), _state_shapes(wl, ecfg, lanes, one_chip))
+    assert drive.memory_analysis().argument_size_in_bytes < 16 << 30
+
+
+def test_raft_drive_carries_pay_as_planes(raft_drive):
+    """The queue's payload reaches the v5e as P ``[lanes, Q]`` planes
+    tiled like the ``[lanes, Q]`` emit masks, Q on the sublanes
+    (``{0,1:T(8,128)}``), and lives so in the drive loop's carry; no
+    stacked ``[lanes, Q, P]`` array, tiled with P on the sublanes, is
+    left anywhere in the program."""
+    hlo = raft_drive.as_text()
+    wl, ecfg, _ = _model("raft")
+    q, p = ecfg.queue_capacity, wl.payload_slots
+    assert f"s32[{RAFT_LANES},{q},{p}]" not in hlo
+    plane = re.escape(f"s32[{RAFT_LANES},{q}]{{0,1:T(8,128)")
+    params = re.findall(rf"%state_queue_pay_(\d+)_\S* = {plane}\S* parameter\(", hlo)
+    assert sorted(map(int, params)) == list(range(p)), params
+    (carry,) = [line for line in hlo.splitlines() if re.match(r"\s*%while\S* = \(", line)]
+    assert len(re.findall(plane, carry)) >= p + 1  # the kind plane and P pay planes
 
 
 def test_etcd_screen_compiles_for_v5e(one_chip):
