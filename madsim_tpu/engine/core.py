@@ -426,7 +426,9 @@ def run_drive(
     return final
 
 
-# The step's phases, as ``step_one`` and ``_pop_event`` scope them.
+# The step's phases, as ``step_one`` and ``_pop_event`` scope them. A
+# scope a workload's handler opens directly inside ``handler`` (raft's
+# ``snapshot``) names a phase of its own.
 PHASES = ("rng", "pop", "handler", "push", "commit")
 _INSTR = re.compile(r"^\s+(ROOT )?(%[\w.\-]+) = (.*)$")
 _OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
@@ -436,11 +438,15 @@ _SCOPE = re.compile(r"(?:vmap\()*(\w+)\)*")
 
 def _scope_phase(op_name: str) -> Optional[str]:
     """The innermost phase scope named in an ``op_name`` path, through
-    the ``vmap(...)`` the batch axis wraps it in; None outside them."""
-    for part in reversed(op_name.split("/")):
-        m = _SCOPE.fullmatch(part)
-        if m and m.group(1) in PHASES:
-            return m.group(1)
+    the ``vmap(...)`` the batch axis wraps it in, or the scope nested
+    directly inside ``handler`` (a path part after it that is not the
+    op's own name); None outside them."""
+    parts = op_name.split("/")
+    names = [m.group(1) if m else None for m in map(_SCOPE.fullmatch, parts)]
+    for i in range(len(names) - 1, -1, -1):
+        if names[i] in PHASES:
+            inner = names[i + 1] if i + 2 < len(names) else None
+            return inner if names[i] == "handler" and inner else names[i]
     return None
 
 
@@ -448,9 +454,10 @@ def hlo_phases(text: str) -> dict:
     """Map every instruction outside a fused computation of a compiled
     module's text (``compiled.as_text()``: the names the profiler's "XLA
     Ops" line shows, ``%while.31``) to the phase scope its
-    ``metadata={op_name=...}`` names, or None. A fusion takes the phase
-    of its fused computation's root (for a tuple root, the first operand
-    with one)."""
+    ``metadata={op_name=...}`` names (the innermost, so an op under
+    ``handler/snapshot`` is ``snapshot``), or None. A fusion takes the
+    phase of its fused computation's root (for a tuple root, the first
+    operand with one)."""
     comps, comp = {}, None  # computation -> [(name, rest of line, root?)]
     own, fused = {}, {}  # name -> phase of its own metadata / fused comp
     for line in text.splitlines():
@@ -493,14 +500,14 @@ def hlo_phases(text: str) -> dict:
 
 
 def drive_phase_map() -> dict:
-    """HLO instruction name -> step phase (``PHASES``) or None, over every
-    drive program this process dispatched through ``run_drive``. Read
-    from each compiled module's text, so ask for it after the measured
-    window: the first call compiles each program again, which a
-    persistent compilation cache turns into a load. A name the drive
-    shares with its ``_init`` program is left out (a trace keys ops by
-    name across programs), and so is a name two drive programs give
-    different phases."""
+    """HLO instruction name -> step phase (``PHASES``, or a scope of the
+    handler's own) or None, over every drive program this process
+    dispatched through ``run_drive``. Read from each compiled module's
+    text, so ask for it after the measured window: the first call
+    compiles each program again, which a persistent compilation cache
+    turns into a load. A name the drive shares with its ``_init``
+    program is left out (a trace keys ops by name across programs), and
+    so is a name two drive programs give different phases."""
     out, clash = {}, set()
     for (workload, cfg, _lanes), prog in _DRIVE_PROGRAMS.items():
         if prog[2] is None:

@@ -4,6 +4,7 @@ trace), and engine sweep checkpoint/resume."""
 import json
 import logging
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -350,6 +351,42 @@ _RAFT3_FINGERPRINT = (
     "hist_slots=0, faults=None, event_mix=False),)"
     "|(48, 500000000, 4000, 50, 100)|cover137|hist0|emix0"
 )
+
+
+_RAFT5_FINGERPRINT = (
+    "madsim_tpu.models.raft._init|(RaftConfig(num_nodes=5, "
+    "election_lo_ns=150000000, election_hi_ns=300000000, "
+    "heartbeat_ns=50000000, commands=8, cmd_window_ns=4000000000, "
+    "cmd_retry_ns=50000000, cmd_max_retries=64, log_cap=32, crashes=1, "
+    "crash_window_ns=5000000000, restart_lo_ns=100000000, "
+    "restart_hi_ns=1000000000, loss_q32=42949673, lat_lo_ns=1000000, "
+    "lat_hi_ns=10000000, buggify_q32=0, history=16, volatile_state=False, "
+    "hist_slots=0, faults=None, event_mix=False),)"
+    "|(60, 3000000000, 200000, 50, 100)|cover227|hist0|emix0"
+)
+
+
+def test_raft_without_compaction_keeps_its_state_and_fingerprint():
+    """Log compaction and command rounds are opt-in: at their defaults
+    raft5 (the benchmark's configuration) carries no state leaf of
+    them, 8 payload words and 5 event kinds, and its checkpoint
+    fingerprint is the one it had before they existed."""
+    from functools import partial
+
+    cfg = raft.RaftConfig(num_nodes=5, crashes=1)
+    ecfg = raft.engine_config(cfg, queue_capacity=60, time_limit_ns=3_000_000_000,
+                              max_steps=200_000, jitter_lo_ns=50, jitter_hi_ns=100)
+    wl = raft.workload(cfg)
+    state = jax.eval_shape(partial(ecore._init_one, wl, ecfg),
+                           jax.ShapeDtypeStruct((), jnp.int64), None)
+    assert state.wstate.snap == ()
+    assert len(jax.tree.leaves(state)) == 69
+    assert ecore.state_bytes_per_seed(wl, ecfg) == 4256
+    assert wl.payload_slots == raft.payload_slots(cfg) == raft.PAYLOAD_SLOTS == 8
+    assert raft.n_kinds(cfg) == raft.N_KINDS and wl.cover_bits == 227
+    assert checkpoint._sweep_fingerprint(wl, ecfg) == _RAFT5_FINGERPRINT
+    snap = raft.RaftConfig(num_nodes=3, snapshot_every=4, rounds=2)
+    assert raft.payload_slots(snap) == 9 and raft.n_kinds(snap) == 6
 
 
 def test_checkpoint_fingerprint_is_stable(tmp_path, monkeypatch):

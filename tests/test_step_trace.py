@@ -4,6 +4,7 @@ profiler's clock."""
 
 import glob
 import json
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +59,40 @@ def test_hlo_phases_reads_fusion_roots_and_scopes():
     # phase is not a phase scope
     assert phases == {"%x": None, "%fusion.1": "push", "%fusion.2": "rng",
                       "%copy.3": None, "%sort.4": "handler"}
+
+
+def test_hlo_phases_names_the_innermost_phase():
+    hlo = HLO.replace("vmap(handler)/jit(pop)/sort",
+                      "vmap(handler)/vmap(snapshot)/sort")
+    assert core.hlo_phases(hlo)["%sort.4"] == "snapshot"
+
+
+def _drive_text(wl, ecfg):
+    state = jax.eval_shape(partial(core.init_sweep, wl, ecfg),
+                           jax.ShapeDtypeStruct((8,), jnp.int64))
+    return core._drive.lower(wl, ecfg, state).compile().as_text()
+
+
+def test_snapshot_scope_is_a_phase_and_leaves_raft_without_it_unchanged(
+        monkeypatch):
+    snap = raft.RaftConfig(num_nodes=3, crashes=0, commands=1, log_cap=16,
+                           snapshot_every=4, rounds=2)
+    wl = raft.workload(snap)
+    phases = core.hlo_phases(_drive_text(wl, raft.engine_config(snap)))
+    assert "snapshot" in phases.values() and "handler" in phases.values()
+    text = _drive_text(WL, ECFG)
+    plain = core.hlo_phases(text)
+    assert "snapshot" not in plain.values()
+
+    def step_phase(op_name):  # the rule before handler scopes were phases
+        for part in reversed(op_name.split("/")):
+            m = core._SCOPE.fullmatch(part)
+            if m and m.group(1) in core.PHASES:
+                return m.group(1)
+        return None
+
+    monkeypatch.setattr(core, "_scope_phase", step_phase)
+    assert core.hlo_phases(text) == plain
 
 
 def _host_finals(seeds):

@@ -15,6 +15,7 @@ persistent compile cache is off around these compiles: an entry written
 for a described chip cannot be read back without one.
 """
 
+import json
 import os
 import re
 from functools import partial
@@ -77,12 +78,20 @@ def _compile(fn, *args):
 
 def _model(model):
     """(workload, engine config, lanes): raft as the benchmark runs it
-    (5 nodes, 3 virtual s), the others at their default configs and
+    (5 nodes, 3 virtual s), raft3snap as its benchmark configuration
+    gives it, the others at their default configs, each at
     ``core.pick_chunk_size`` lanes."""
     if model == "raft":
         cfg = raft.RaftConfig(num_nodes=5, crashes=1)
         wl = raft.workload(cfg)
         return wl, raft.engine_config(cfg, time_limit_ns=3_000_000_000), RAFT_LANES
+    if model == "raft3snap":
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "benchmark", "configs", "raft3snap.json")) as f:
+            spec = json.load(f)
+        cfg = raft.RaftConfig(**spec["config"])
+        wl, ecfg = raft.workload(cfg), raft.engine_config(cfg, **spec["engine"])
+        return wl, ecfg, core.pick_chunk_size(wl, ecfg)
     mod = {"etcd": etcd, "kafka": kafka, "s3": s3}[model]
     wl, ecfg = mod.workload(), mod.engine_config()
     return wl, ecfg, core.pick_chunk_size(wl, ecfg)
@@ -95,7 +104,7 @@ def raft_drive(one_chip):
     return _compile(partial(core.drive, wl, ecfg), _state_shapes(wl, ecfg, lanes, one_chip))
 
 
-@pytest.mark.parametrize("model", ["raft", "etcd", "kafka", "s3"])
+@pytest.mark.parametrize("model", ["raft", "etcd", "kafka", "s3", "raft3snap"])
 def test_sweep_compiles_for_v5e(one_chip, model, request):
     """``init_sweep`` and ``drive`` for each device model."""
     wl, ecfg, lanes = _model(model)
